@@ -20,6 +20,7 @@ import numpy as np
 from repro.configs import reduced_config
 from repro.core import CacheConfig, open_cache
 from repro.core.types import MB
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import init_params
 from repro.serve.engine import Request, ServingEngine
 from repro.storage import RemoteStore, make_dataset
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=8)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = reduced_config(args.arch)
     params = init_params(cfg, jax.random.PRNGKey(0))
 

@@ -18,7 +18,9 @@ callers).
 
 Token shards live in the (simulated) remote object store as big files;
 sample i of a shard maps to a fixed byte range, so the cache sees the same
-block-granular traffic a JuiceFS mount would.
+block-granular traffic a JuiceFS mount would.  The tokens a batch carries
+are decoded from the bytes that range returned through the client
+(:func:`decode_tokens`), so the model trains on what the cache served.
 """
 from __future__ import annotations
 
@@ -30,7 +32,6 @@ import numpy as np
 
 from ..core.client import CacheClient, SimExecutor, ThreadedExecutor
 from ..core.sharded import Engine
-from ..core.types import MB, PathT, block_key
 from ..storage.datasets import DatasetSpec, make_dataset
 from ..storage.object_store import RemoteStore
 
@@ -38,6 +39,13 @@ from ..storage.object_store import RemoteStore
 def make_token_dataset(name: str, n_shards: int, shard_bytes: int) -> DatasetSpec:
     return make_dataset(name, "big_files", n_files=n_shards,
                         file_size=shard_bytes)
+
+
+def decode_tokens(raw: np.ndarray, n_tokens: int, vocab: int) -> np.ndarray:
+    """The first ``n_tokens`` little-endian uint32 words of a sample's
+    bytes, each taken modulo ``vocab``."""
+    words = np.frombuffer(raw, dtype="<u4", count=n_tokens)
+    return (words % vocab).astype(np.int32)
 
 
 @dataclass
@@ -93,6 +101,9 @@ class CachedTokenPipeline:
         self.vocab = vocab
         self.rng = np.random.default_rng(seed)
         self.sample_bytes = sample_bytes or (seq_len + 1) * 4
+        if self.sample_bytes < (seq_len + 1) * 4:
+            raise ValueError(f"sample_bytes={self.sample_bytes} holds fewer "
+                             f"than seq_len + 1 = {seq_len + 1} tokens")
         self.access_pattern = access_pattern
         self.stats = PipelineStats()
         self._samples = []
@@ -101,12 +112,6 @@ class CachedTokenPipeline:
             for i in range(n):
                 self._samples.append((f.path, i * self.sample_bytes))
 
-    def _account_outcome(self, out) -> None:
-        self.stats.cache_hits += sum(1 for b in out.blocks if b.hit)
-        self.stats.cache_misses += sum(1 for b in out.blocks if not b.hit)
-        self.stats.bytes_read += self.sample_bytes
-        self._sync_prefetch_stats()
-
     def _sync_prefetch_stats(self) -> None:
         ex = self.client.executor.stats
         base = self._ex_base
@@ -114,21 +119,14 @@ class CachedTokenPipeline:
         self.stats.prefetch_completed = ex.completed - base[1]
         self.stats.prefetch_cancelled = ex.cancelled - base[2]
 
-    def _synth_tokens(self, fpath: PathT, offset: int) -> np.ndarray:
-        # deterministic synthetic tokens for the sample's byte range
-        block = offset // (4 * MB)
-        raw = self.store.fetch_block(block_key(fpath, block),
-                                     self.sample_bytes)
-        tokens = raw.astype(np.int64)
-        tokens = (tokens[0::4] * 16777619 + tokens[1::4] * 65537
-                  + tokens[2::4] * 257 + tokens[3::4]) % self.vocab
-        return tokens[: self.seq_len + 1].astype(np.int32)
-
-    def _read_sample(self, fpath: PathT, offset: int) -> np.ndarray:
-        res = self.client.read(fpath, offset, self.sample_bytes,
-                               time.monotonic())
-        self._account_outcome(res.outcome)
-        return self._synth_tokens(fpath, offset)
+    def _tokens(self, res) -> np.ndarray:
+        """Account one sample's read and decode its tokens."""
+        blocks = res.outcome.blocks
+        self.stats.cache_hits += sum(1 for b in blocks if b.hit)
+        self.stats.cache_misses += sum(1 for b in blocks if not b.hit)
+        self.stats.bytes_read += res.data.nbytes
+        self._sync_prefetch_stats()
+        return decode_tokens(res.data, self.seq_len + 1, self.vocab)
 
     def batches(self, epochs: int = 1) -> Iterator[Dict[str, np.ndarray]]:
         order = np.arange(len(self._samples))
@@ -142,11 +140,9 @@ class CachedTokenPipeline:
                 # through the kernel in one call (tick cadence amortized
                 # per batch); prefetch dispatch is the executor's job
                 results = self.client.read_batch(
-                    [(fp, off, self.sample_bytes) for fp, off in group], now)
-                for res in results:
-                    self._account_outcome(res.outcome)
-                toks = [self._synth_tokens(fp, off) for fp, off in group]
-                arr = np.stack(toks)
+                    [(fp, off, self.sample_bytes) for fp, off in group], now,
+                    fetch=True)
+                arr = np.stack([self._tokens(res) for res in results])
                 self.stats.batches += 1
                 yield {"tokens": arr[:, :-1], "labels": arr[:, 1:]}
 

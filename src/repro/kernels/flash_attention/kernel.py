@@ -9,6 +9,11 @@ Block shapes are MXU-aligned (q/kv tiles multiples of 128 on the contracting
 dim, head_dim itself 64/128).  VMEM footprint per step:
   q (Bq, hd) bf16 + k,v (Bk, hd) bf16 + acc (Bq, hd) f32 + m,l (Bq,) f32
 ≈ 0.8 MB at Bq=Bk=512, hd=128 — well inside the ~16 MB VMEM budget.
+
+Differentiable through ``jax.custom_vjp``: the forward is the Pallas kernel;
+the backward is the VJP of the jnp oracle (``ref.flash_attention_ref``),
+recomputed from the saved q, k, v.  On a TPU that backward is ordinary XLA
+code in the same program as the kernel.
 """
 from __future__ import annotations
 
@@ -19,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .ref import flash_attention_ref
 
 NEG_INF = -1e30
 
@@ -69,16 +76,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                        ).astype(o_ref.dtype)
 
 
-def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                           causal: bool = True, q_offset: int = 0,
-                           block_q: int = 512, block_kv: int = 512,
-                           softmax_scale=None,
-                           interpret: bool = False) -> jax.Array:
-    """q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd)."""
+def _flash_call(q, k, v, causal, q_offset, block_q, block_kv, scale,
+                interpret):
     B, Sq, H, hd = q.shape
     _, Skv, KV, _ = k.shape
     groups = H // KV
-    scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(hd)
     block_q = min(block_q, Sq)
     block_kv = min(block_kv, Skv)
     kv_valid = Skv
@@ -121,3 +123,40 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, q_offset, block_q, block_kv, scale, interpret):
+    return _flash_call(q, k, v, causal, q_offset, block_q, block_kv, scale,
+                       interpret)
+
+
+def _flash_fwd(q, k, v, causal, q_offset, block_q, block_kv, scale,
+               interpret):
+    out = _flash_call(q, k, v, causal, q_offset, block_q, block_kv, scale,
+                      interpret)
+    return out, (q, k, v)
+
+
+def _flash_bwd(causal, q_offset, block_q, block_kv, scale, interpret, res,
+               g):
+    ref = functools.partial(flash_attention_ref, causal=causal,
+                            q_offset=q_offset, block_kv=block_kv,
+                            softmax_scale=scale)
+    _, vjp = jax.vjp(ref, *res)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                           causal: bool = True, q_offset: int = 0,
+                           block_q: int = 512, block_kv: int = 512,
+                           softmax_scale=None,
+                           interpret: bool = False) -> jax.Array:
+    """q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd)."""
+    scale = (softmax_scale if softmax_scale is not None
+             else 1.0 / math.sqrt(q.shape[-1]))
+    return _flash(q, k, v, causal, q_offset, block_q, block_kv, scale,
+                  interpret)

@@ -3,25 +3,22 @@ tests).  The two paths are numerically cross-checked in
 tests/test_kernels.py (interpret=True)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 
+from ...sharding.context import batch_parallel
 from .kernel import flash_attention_pallas
 from .ref import decode_attention_ref, flash_attention_ref
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
 def flash_attention(q, k, v, *, causal=True, q_offset=0, block_kv=1024,
                     softmax_scale=None, force_ref=False):
-    if _on_tpu() and not force_ref:
-        return flash_attention_pallas(q, k, v, causal=causal,
-                                      q_offset=q_offset,
-                                      softmax_scale=softmax_scale)
+    if jax.default_backend() == "tpu" and not force_ref:
+        kernel = functools.partial(flash_attention_pallas, causal=causal,
+                                   q_offset=q_offset,
+                                   softmax_scale=softmax_scale)
+        return batch_parallel(kernel, (q, k, v), (True, True, True))
     return flash_attention_ref(q, k, v, causal=causal, q_offset=q_offset,
                                block_kv=block_kv, softmax_scale=softmax_scale)
 

@@ -3,43 +3,22 @@ TPU; full jnp oracle elsewhere."""
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
+from ...sharding.context import batch_parallel
 from .kernel import ssd_chunk_pallas
-from .ref import segsum, ssd_decode_ref, ssd_ref
+from .ref import ssd_decode_ref, ssd_ref
 
 
 def ssd(x, a, B, C, chunk: int = 256, initial_state=None, force_ref=False):
     if jax.default_backend() != "tpu" or force_ref:
         return ssd_ref(x, a, B, C, chunk=chunk, initial_state=initial_state)
-    b, s, h, p = x.shape
-    n = B.shape[-1]
-    if s % chunk:
-        pad = chunk - s % chunk
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
-        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
-        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
-    sp = x.shape[1]
-    c = sp // chunk
-    xc = x.astype(jnp.float32).reshape(b, c, chunk, h, p)
-    ac = a.astype(jnp.float32).reshape(b, c, chunk, h)
-    Bc = B.astype(jnp.float32).reshape(b, c, chunk, n)
-    Cc = C.astype(jnp.float32).reshape(b, c, chunk, n)
-    y_diag, states = ssd_chunk_pallas(xc, ac, Bc, Cc)
-    # inter-chunk recurrence (small) in jnp
-    a_cum = jnp.cumsum(ac.transpose(0, 3, 1, 2), axis=-1)        # (b,h,c,l)
-    if initial_state is None:
-        initial_state = jnp.zeros((b, h, p, n), jnp.float32)
-    states = jnp.concatenate([initial_state[:, None], states], axis=1)
-    chunk_decay = a_cum[..., -1]
-    dc = jnp.exp(segsum(jnp.pad(chunk_decay, ((0, 0), (0, 0), (1, 0)))))
-    new_states = jnp.einsum("bhzc,bchpn->bzhpn", dc, states)
-    carry, final = new_states[:, :-1], new_states[:, -1]
-    out_decay = jnp.exp(a_cum)
-    y_off = jnp.einsum("bcln,bchpn,bhcl->bclhp", Cc, carry, out_decay)
-    y = (y_diag + y_off).reshape(b, sp, h, p)[:, :s]
-    return y.astype(x.dtype), final
+
+    def chunk_fn(xc, ac, Bc, Cc):
+        return batch_parallel(ssd_chunk_pallas, (xc, ac, Bc, Cc),
+                              (True, True, True, True))
+
+    return ssd_ref(x, a, B, C, chunk=chunk, initial_state=initial_state,
+                   chunk_fn=chunk_fn)
 
 
 def ssd_decode(x_t, a_t, B_t, C_t, state):

@@ -27,7 +27,25 @@ def segsum(x):
     return jnp.where(mask, d, NEG_INF)
 
 
-def ssd_ref(x, a, B, C, chunk: int = 256, initial_state=None):
+def ssd_chunk_ref(xc, ac, Bc, Cc):
+    """Intra-chunk block (the Pallas kernel's oracle).
+
+    xc (b, c, l, h, p); ac (b, c, l, h); Bc/Cc (b, c, l, n) →
+    (y_diag (b, c, l, h, p), chunk output states (b, c, h, p, n))."""
+    at = ac.transpose(0, 3, 1, 2)                          # (b,h,c,l)
+    a_cum = jnp.cumsum(at, axis=-1)
+    L = jnp.exp(segsum(at))                                # (b,h,c,l,l)
+    y_diag = jnp.einsum("bcln,bcsn,bhcls,bcshp->bclhp", Cc, Bc, L, xc)
+    decay_states = jnp.exp(a_cum[..., -1:] - a_cum)        # (b,h,c,l)
+    states = jnp.einsum("bcln,bhcl,bclhp->bchpn", Bc, decay_states, xc)
+    return y_diag, states
+
+
+def ssd_ref(x, a, B, C, chunk: int = 256, initial_state=None,
+            chunk_fn=ssd_chunk_ref):
+    """Chunked SSD.  ``chunk_fn`` computes the intra-chunk block with
+    :func:`ssd_chunk_ref`'s contract; the TPU path passes the Pallas
+    kernel, everything around it is shared."""
     b, s, h, p = x.shape
     n = B.shape[-1]
     if s % chunk:
@@ -40,17 +58,12 @@ def ssd_ref(x, a, B, C, chunk: int = 256, initial_state=None):
     c = sp // chunk
 
     xc = x.astype(jnp.float32).reshape(b, c, chunk, h, p)
-    ac = a.astype(jnp.float32).reshape(b, c, chunk, h).transpose(0, 3, 1, 2)  # (b,h,c,l)
+    ac = a.astype(jnp.float32).reshape(b, c, chunk, h)
     Bc = B.astype(jnp.float32).reshape(b, c, chunk, n)
     Cc = C.astype(jnp.float32).reshape(b, c, chunk, n)
 
-    a_cum = jnp.cumsum(ac, axis=-1)                       # (b,h,c,l)
-    L = jnp.exp(segsum(ac))                               # (b,h,c,l,l)
-    # intra-chunk
-    y_diag = jnp.einsum("bcln,bcsn,bhcls,bcshp->bclhp", Cc, Bc, L, xc)
-    # chunk output states
-    decay_states = jnp.exp(a_cum[..., -1:] - a_cum)       # (b,h,c,l)
-    states = jnp.einsum("bcln,bhcl,bclhp->bchpn", Bc, decay_states, xc)
+    y_diag, states = chunk_fn(xc, ac, Bc, Cc)
+    a_cum = jnp.cumsum(ac.transpose(0, 3, 1, 2), axis=-1)  # (b,h,c,l)
     # inter-chunk recurrence
     if initial_state is None:
         initial_state = jnp.zeros((b, h, p, n), jnp.float32)

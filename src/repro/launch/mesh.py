@@ -10,9 +10,9 @@ from __future__ import annotations
 import jax
 
 
-def _mk(shape, axes):
+def _mk(shape, axes, devices=None):
     auto = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, axis_types=auto)
+    return jax.make_mesh(shape, axes, axis_types=auto, devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,6 +21,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _mk(shape, axes)
 
 
-def make_local_mesh():
-    """1-device mesh for CPU smoke tests (same axis names, all size 1)."""
-    return _mk((1, 1), ("data", "model"))
+def make_local_mesh(devices=None):
+    """Mesh over this host's devices (default: all of them), with the
+    production axis names: ``("data", "model") = (n, 1)``."""
+    devices = jax.devices() if devices is None else list(devices)
+    return _mk((len(devices), 1), ("data", "model"), devices)

@@ -61,8 +61,20 @@ class ServingEngine:
         self.done: List[Request] = []
         self._slots: List[Optional[Request]] = [None] * batch
         self.state = init_decode_state(cfg, batch, max_seq)
-        self._decode = jax.jit(
+        self._decode_jit = jax.jit(
             lambda p, s, t: decode_step(p, cfg, s, t))
+        self.decode_compiled = None     # jax.stages.Compiled, after warmup
+        self.compile_s = 0.0
+
+    def _decode(self, toks: jax.Array):
+        """One decode step; the first call compiles it ahead of time, so
+        ``compile_s`` and the compiled program can be read back."""
+        if self.decode_compiled is None:
+            t0 = time.perf_counter()
+            self.decode_compiled = self._decode_jit.lower(
+                self.params, self.state, toks).compile()
+            self.compile_s = time.perf_counter() - t0
+        return self.decode_compiled(self.params, self.state, toks)
 
     # ---------------------------------------------------------------- admit
     def submit(self, req: Request) -> None:
@@ -109,8 +121,7 @@ class ServingEngine:
                     toks[i, 0] = req.prompt[feed_pos[i]]
                 elif req.output:
                     toks[i, 0] = req.output[-1]
-            logits, self.state = self._decode(self.params, self.state,
-                                              jnp.asarray(toks))
+            logits, self.state = self._decode(jnp.asarray(toks))
             nxt = np.asarray(logits[:, -1].argmax(-1))
             for i, req in enumerate(self._slots):
                 if req is None:

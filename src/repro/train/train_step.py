@@ -101,24 +101,26 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig, mesh: Mesh,
     return train_step
 
 
+def train_shardings(cfg: ModelConfig, shape: ShapeSpec, mesh: Mesh,
+                    rules: Optional[LogicalRules] = None):
+    """(params, optimizer state, batch) shardings of one train step."""
+    params_sh = shardings_for(build_specs(cfg), mesh, rules)
+    opt_sh = AdamWState(NamedSharding(mesh, P()), params_sh, params_sh)
+    return params_sh, opt_sh, batch_shardings(cfg, shape, mesh, rules)
+
+
 def lower_train_step(cfg: ModelConfig, shape: ShapeSpec, mesh: Mesh,
                      rules: Optional[LogicalRules] = None, *,
                      remat: str = "full", microbatches: int = 1,
                      opt_cfg: Optional[AdamWConfig] = None, unroll: int = 1,
                      loss_impl: str = "dense"):
-    """AOT-lower the train step on abstract inputs (the dry-run entry)."""
+    """AOT-lower the train step on abstract inputs (the dry-run entry, and
+    the trainer's own compile)."""
     opt_cfg = opt_cfg or AdamWConfig()
-    specs = build_specs(cfg)
-    params_s = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype), specs,
-        is_leaf=lambda x: hasattr(x, "axes") and hasattr(x, "init"))
-    params_sh = shardings_for(specs, mesh, rules)
+    params_s = abstract_params(cfg)
     opt_s = abstract_state(params_s)
-    opt_sh = AdamWState(
-        NamedSharding(mesh, P()),
-        jax.tree.map(lambda s: s, params_sh), params_sh)
     batch_s = batch_structs(cfg, shape)
-    batch_sh = batch_shardings(cfg, shape, mesh, rules)
+    params_sh, opt_sh, batch_sh = train_shardings(cfg, shape, mesh, rules)
 
     step = make_train_step(cfg, opt_cfg, mesh, rules, remat=remat,
                            microbatches=microbatches, unroll=unroll,

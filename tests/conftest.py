@@ -5,20 +5,3 @@ import sys
 # for the dry-run entry point, per the assignment).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import pytest  # noqa: E402
-
-# Capability gate for the explicit-mesh-axis-type tests: the image's jax
-# predates ``jax.sharding.AxisType`` (used by repro.launch.mesh), which is a
-# toolchain gap, not a cache regression — skip with a reason instead of
-# hard-erroring (the pre-PR-2 state was 9 hard failures).  The cache core
-# itself needs only numpy, so a jax-less environment must still collect and
-# run the rest of the suite.
-try:
-    import jax  # noqa: E402
-    HAS_MESH_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-except ImportError:
-    HAS_MESH_AXIS_TYPES = False
-requires_mesh_axis_types = pytest.mark.skipif(
-    not HAS_MESH_AXIS_TYPES,
-    reason="installed jax lacks jax.sharding.AxisType (explicit mesh axis "
-           "types required by repro.launch.mesh.make_local_mesh)")

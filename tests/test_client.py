@@ -352,6 +352,26 @@ def _token_world():
     return store, ccfg
 
 
+class SlowPrefetchStore:
+    """BackingStore wrapper that stalls the small, capped fetches of
+    prefetch candidates (``max_fetch_bytes``) and serves the pipeline's
+    full-sample demand reads at once: the shard worker is busy while a
+    batch submits its candidates, so a depth-1 queue must overflow."""
+
+    def __init__(self, store, small: int, delay_s: float):
+        self.store = store
+        self.small = small
+        self.delay_s = delay_s
+
+    def fetch_block(self, path, size):
+        if size <= self.small:
+            time.sleep(self.delay_s)
+        return self.store.fetch_block(path, size)
+
+    def __getattr__(self, name):
+        return getattr(self.store, name)
+
+
 def test_pipeline_stats_expose_cancelled_vs_completed():
     # one sample per small file → a sequential epoch is a file scan that
     # keeps issuing file-level readahead candidates
@@ -361,22 +381,21 @@ def test_pipeline_stats_expose_cancelled_vs_completed():
     ccfg = CacheConfig(min_share=4 * MB, rebalance_quantum=4 * MB,
                        window=40, reanalyze_every=20)
     engine = IGTCache(store, 64 * MB, cfg=ccfg)
-    gated = GatedStore(store)
+    slow = SlowPrefetchStore(store, small=512, delay_s=0.02)
     ex = ThreadedExecutor(queue_depth=1, max_fetch_bytes=512)
-    client = CacheClient(engine, backing=gated, executor=ex)
+    client = CacheClient(engine, backing=slow, executor=ex)
     pipe = CachedTokenPipeline(store, client, "corpus", seq_len=32, batch=4,
                                vocab=1000, sample_bytes=64 * 1024,
                                access_pattern="sequential")
     for _ in pipe.batches(epochs=1):
         pass
-    gated.gate.set()
     pipe.flush(timeout=10.0)
     client.close()
     pipe.close()
     s = pipe.stats
     assert s.prefetch_submitted > 0, "sequential scan issued no candidates"
     assert s.prefetch_cancelled > 0, \
-        "depth-1 queue behind a gated store must overflow-cancel"
+        "depth-1 queue behind a stalled worker must overflow-cancel"
     assert s.prefetch_completed + s.prefetch_cancelled <= s.prefetch_submitted
     assert not engine._pending_prefetch    # nothing silently dropped
 

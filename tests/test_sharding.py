@@ -7,9 +7,6 @@ from repro.launch.mesh import make_local_mesh
 from repro.models.params import p, tree_abstract, tree_init
 from repro.sharding import DEFAULT_RULES, apply_rules, shardings_for
 from repro.sharding.context import constrain, sharding_ctx
-from conftest import requires_mesh_axis_types
-
-pytestmark = requires_mesh_axis_types
 
 
 def test_apply_rules_local_mesh_all_replicated_when_indivisible():

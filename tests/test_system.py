@@ -16,8 +16,6 @@ from repro.storage import RemoteStore
 from repro.train.optimizer import AdamWConfig, init_state
 from repro.train.train_step import make_train_step
 
-from conftest import requires_mesh_axis_types
-
 
 @pytest.fixture(scope="module")
 def world():
@@ -28,7 +26,6 @@ def world():
     return store, cfg
 
 
-@requires_mesh_axis_types
 def test_pipeline_trains_through_cache(world):
     store, ccfg = world
     engine = IGTCache(store, 16 * MB, cfg=ccfg)
@@ -67,6 +64,40 @@ def test_pipeline_epoch2_hits_cache(world):
             break
     assert engine.hit_ratio() > 0.45          # epoch 2 ~fully cached
     pipe.close()
+
+
+def _first_batch(world):
+    """Samples 0-3 of shard 0, read in order: all inside its first block."""
+    store, ccfg = world
+    engine = IGTCache(store, 16 * MB, cfg=ccfg)
+    pipe = CachedTokenPipeline(store, engine, "corpus", seq_len=32, batch=4,
+                               vocab=1000, background_prefetch=False,
+                               access_pattern="sequential")
+    batch = next(pipe.batches())
+    pipe.close()
+    assert all(off + pipe.sample_bytes <= ccfg.block_size
+               for _, off in pipe._samples[:4])
+    return store, pipe, batch
+
+
+def test_pipeline_tokens_are_the_sample_bytes(world):
+    """Each sample's tokens are its own bytes in the store, as little-endian
+    uint32 words modulo the vocab, and bytes_read counts them."""
+    store, pipe, batch = _first_batch(world)
+    for i, (fp, off) in enumerate(pipe._samples[:4]):
+        raw = bytes(store.fetch_range(fp, off, pipe.sample_bytes))
+        words = np.frombuffer(raw, dtype="<u4")[:33]
+        want = (words % 1000).astype(np.int32)
+        np.testing.assert_array_equal(batch["tokens"][i], want[:-1])
+        np.testing.assert_array_equal(batch["labels"][i], want[1:])
+    assert pipe.stats.bytes_read == 4 * pipe.sample_bytes
+
+
+def test_pipeline_samples_in_one_block_differ(world):
+    _, _, batch = _first_batch(world)
+    toks = batch["tokens"]
+    assert all(not np.array_equal(toks[i], toks[j])
+               for i in range(4) for j in range(i + 1, 4))
 
 
 def test_serving_engine_with_rag_cache(world):

@@ -17,8 +17,6 @@ from repro.train.optimizer import (AdamWConfig, apply_updates, global_norm,
                                    init_state, schedule)
 from repro.train.train_step import make_train_step
 
-from conftest import requires_mesh_axis_types
-
 
 def test_adamw_converges_quadratic():
     cfg = AdamWConfig(lr=0.1, warmup_steps=5, total_steps=200,
@@ -49,7 +47,6 @@ def test_grad_clip_applies():
     assert float(metrics["grad_norm"]) > 1e6  # reported pre-clip
 
 
-@requires_mesh_axis_types
 def test_train_step_reduces_loss_tiny_model():
     cfg = reduced_config("qwen3-1.7b")
     mesh = make_local_mesh()
@@ -88,7 +85,6 @@ def test_checkpoint_roundtrip_and_resume(tmp_path):
     assert len(steps) == 2
 
 
-@requires_mesh_axis_types
 def test_checkpoint_elastic_restore_new_sharding(tmp_path):
     """Restore applies target shardings (elastic: mesh may differ)."""
     mesh = make_local_mesh()

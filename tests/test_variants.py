@@ -13,8 +13,6 @@ from repro.sharding import DEFAULT_RULES
 from repro.train.optimizer import AdamWConfig, init_state
 from repro.train.train_step import make_train_step
 
-from conftest import requires_mesh_axis_types
-
 ALL_VARIANTS = ["fsdp_pod", "no_fsdp", "seq_shard", "expert_data",
                 "vocab_data", "cache_seq_model", "pure_fsdp",
                 "embed_replicated", "decode_weights_stationary",
@@ -66,7 +64,6 @@ def test_chunked_ce_grad_matches_dense():
                                    atol=2e-2, rtol=2e-2)
 
 
-@requires_mesh_axis_types
 def test_train_step_chunked_loss_runs():
     cfg = reduced_config("qwen3-1.7b")
     mesh = make_local_mesh()

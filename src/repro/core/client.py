@@ -69,6 +69,7 @@ import numpy as np
 from .cache import path_key
 from .faults import SHARD_UP, ShardUnavailableError
 from .igtcache import BlockResult, EngineOptions, ReadOutcome
+from .obs import span
 from .sharded import Engine, ShardedIGTCache, make_engine
 from .types import CacheConfig, PathT, block_key
 
@@ -235,8 +236,9 @@ class PrefetchExecutor:
         """Retry-guarded raw range fetch (one ``fetch_many`` call)."""
         assert self.backing is not None, "byte fetch needs a backing store"
         try:
-            return self.retry.call(self.backing.fetch_many, requests,
-                                   on_retry=self._note_retry)
+            with span("igt.store.fetch_many"):
+                return self.retry.call(self.backing.fetch_many, requests,
+                                       on_retry=self._note_retry)
         except BaseException:
             with self._stats_lock:
                 self.stats.fetch_errors += 1
@@ -313,15 +315,23 @@ class NullExecutor(PrefetchExecutor):
 
 class _DemandBatch:
     """One shard's slice of a demand fetch: served by that shard's worker
-    in a single ``fetch_many`` call (shard-parallel batched fetches)."""
+    in a single ``fetch_many`` call (shard-parallel batched fetches).
+    ``started`` is set when the worker takes the batch, ``event`` when it
+    is done."""
 
-    __slots__ = ("requests", "results", "error", "event")
+    __slots__ = ("requests", "results", "error", "started", "event")
 
     def __init__(self, requests: List[RangeRequest]) -> None:
         self.requests = requests
         self.results: Optional[List[np.ndarray]] = None
         self.error: Optional[BaseException] = None
+        self.started = threading.Event()
         self.event = threading.Event()
+
+    def fail(self, error: BaseException) -> None:
+        self.error = error
+        self.started.set()
+        self.event.set()
 
 
 class _ShardQueue:
@@ -473,10 +483,8 @@ class ThreadedExecutor(PrefetchExecutor):
         for q in self._queues:
             with q.cv:
                 while q.demand:
-                    item = q.demand.popleft()
-                    item.error = RuntimeError(
-                        "ThreadedExecutor closed with the fetch in queue")
-                    item.event.set()
+                    q.demand.popleft().fail(RuntimeError(
+                        "ThreadedExecutor closed with the fetch in queue"))
 
     def _cancel_queued(self) -> None:
         for sid, q in enumerate(self._queues):
@@ -535,7 +543,9 @@ class ThreadedExecutor(PrefetchExecutor):
         """Split the demand ranges by shard, hand each shard worker its
         slice as one priority batch (served via a single ``fetch_many``),
         and block until every slice lands — misses of one read/batch
-        fetch shard-parallel."""
+        fetch shard-parallel.  The wait is two spans: until every worker
+        has taken its slice (``igt.client.demand_queued``), then until
+        every slice is done (``igt.client.demand_fetch``)."""
         assert self.backing is not None, "demand fetch needs a backing store"
         with self._stats_lock:
             self.stats.demand_fetches += len(requests)
@@ -543,15 +553,18 @@ class ThreadedExecutor(PrefetchExecutor):
         for i, req in enumerate(requests):
             by_shard.setdefault(self.guard.shard_id(req[0]), []).append(i)
         batches: List[Tuple[List[int], _DemandBatch]] = []
-        for sid, idxs in by_shard.items():
-            batch = _DemandBatch([requests[i] for i in idxs])
-            batches.append((idxs, batch))
-            if not self._queues[sid].put_demand(batch):
-                batch.error = RuntimeError(
-                    "demand fetch on a closed ThreadedExecutor")
-                batch.event.set()
-        for _idxs, batch in batches:
-            batch.event.wait()
+        with span("igt.client.demand_queued"):
+            for sid, idxs in by_shard.items():
+                batch = _DemandBatch([requests[i] for i in idxs])
+                batches.append((idxs, batch))
+                if not self._queues[sid].put_demand(batch):
+                    batch.fail(RuntimeError(
+                        "demand fetch on a closed ThreadedExecutor"))
+            for _idxs, batch in batches:
+                batch.started.wait()
+        with span("igt.client.demand_fetch"):
+            for _idxs, batch in batches:
+                batch.event.wait()
         out: List[Optional[np.ndarray]] = [None] * len(requests)
         for idxs, batch in batches:
             if batch.error is not None:  # re-raise in the reader's thread
@@ -562,18 +575,19 @@ class ThreadedExecutor(PrefetchExecutor):
 
     # -- worker loop --------------------------------------------------------
     def _run(self, sid: int, q: _ShardQueue) -> None:
-        guard = self.guard
         while not self._stop.is_set():
             got = q.get(self.poll_s)
             if got is None:
                 continue
             if isinstance(got, _DemandBatch):
+                got.started.set()
                 # a failing backing store must not kill the shard worker
                 # or strand the blocked reader: hand the error back
                 # through the batch (fetch_ranges already retried
                 # transient errors per the RetryPolicy)
                 try:
-                    got.results = self.fetch_ranges(got.requests)
+                    with span("igt.executor.demand"):
+                        got.results = self.fetch_ranges(got.requests)
                 except BaseException as e:
                     got.error = e
                 finally:
@@ -583,32 +597,36 @@ class ThreadedExecutor(PrefetchExecutor):
                 continue
             path, size, key = got
             try:
-                try:
-                    if self.backing is not None and self.max_fetch_bytes > 0:
-                        # the actual byte movement (capped: content is what
-                        # a real store would stream; the kernel only needs
-                        # sizes), transient failures retried
-                        self.retry.call(
-                            self.backing.fetch_range, path, 0,
-                            min(size, self.max_fetch_bytes),
-                            on_retry=self._note_retry)
-                    with guard.lock_shard(sid):
-                        self.engine.complete_prefetch(path, size,
-                                                      self.clock())
-                    with self._stats_lock:
-                        self.stats.completed += 1
-                except Exception:
-                    # failed past the retry bound → the candidate will
-                    # never complete: release it on the kernel, keep the
-                    # worker alive
-                    with self._stats_lock:
-                        self.stats.fetch_errors += 1
-                    with guard.lock_shard(sid):
-                        self.engine.cancel_prefetch(path)
-                    with self._stats_lock:
-                        self.stats.cancelled += 1
+                with span("igt.executor.prefetch"):
+                    self._complete_background(sid, path, size)
             finally:
                 q.task_done(key)
+
+    def _complete_background(self, sid: int, path: PathT, size: int) -> None:
+        """Fetch one background candidate and complete it on the kernel,
+        or cancel it there when the fetch fails past the retry bound."""
+        guard = self.guard
+        try:
+            if self.backing is not None and self.max_fetch_bytes > 0:
+                # the actual byte movement (capped: content is what a real
+                # store would stream; the kernel only needs sizes),
+                # transient failures retried
+                self.retry.call(self.backing.fetch_range, path, 0,
+                                min(size, self.max_fetch_bytes),
+                                on_retry=self._note_retry)
+            with guard.lock_shard(sid):
+                self.engine.complete_prefetch(path, size, self.clock())
+            with self._stats_lock:
+                self.stats.completed += 1
+        except Exception:
+            # failed past the retry bound → the candidate will never
+            # complete: release it on the kernel, keep the worker alive
+            with self._stats_lock:
+                self.stats.fetch_errors += 1
+            with guard.lock_shard(sid):
+                self.engine.cancel_prefetch(path)
+            with self._stats_lock:
+                self.stats.cancelled += 1
 
 
 class ReadResult:
@@ -736,12 +754,23 @@ class CacheClient:
         (optionally) bytes for the requested range.  A dead shard
         degrades to a direct store fetch instead of raising (see the
         class docstring)."""
+        with span("igt.client.read"):
+            return self._read(file_path, offset, size, now, fetch)
+
+    def _read(self, file_path: PathT, offset: int, size: int,
+              now: Optional[float], fetch: Optional[bool]) -> ReadResult:
         if now is None:
             now = self.clock()
         degraded = False
+        lock = self.guard.lock_for(file_path)
         try:
-            with self.guard.lock_for(file_path):
-                out = self.engine.read(file_path, offset, size, now)
+            with span("igt.kernel.lock_wait"):
+                lock.acquire()
+            try:
+                with span("igt.kernel.read"):
+                    out = self.engine.read(file_path, offset, size, now)
+            finally:
+                lock.release()
         except ShardUnavailableError:
             if not self.degraded:
                 raise
@@ -750,7 +779,8 @@ class CacheClient:
             with self._cstats_lock:
                 self.client_stats.degraded_reads += 1
         if out.prefetches:
-            self.executor.submit(out.prefetches, now)
+            with span("igt.client.submit"):
+                self.executor.submit(out.prefetches, now)
         want = self.fetch_bytes if fetch is None else fetch
         if not want or not out.blocks:
             return ReadResult(out)
@@ -773,13 +803,21 @@ class CacheClient:
         (one ``fetch_many`` per shard under the ThreadedExecutor).  When
         a shard is down only its sub-batch degrades to direct store
         fetches; the surviving shards' outcomes are kept as-is."""
+        with span("igt.client.read_batch"):
+            return self._read_batch(requests, now, fetch)
+
+    def _read_batch(self, requests: Sequence[Tuple[PathT, int, int]],
+                    now: Optional[float],
+                    fetch: Optional[bool]) -> List[ReadResult]:
         if now is None:
             now = self.clock()
         requests = list(requests)
         degraded_idx: Set[int] = set()
-        self.guard.acquire_all()
+        with span("igt.kernel.lock_wait"):
+            self.guard.acquire_all()
         try:
-            outs = self.engine.read_batch(requests, now)
+            with span("igt.kernel.read"):
+                outs = self.engine.read_batch(requests, now)
         except ShardUnavailableError as e:
             if not self.degraded:
                 raise
@@ -798,9 +836,10 @@ class CacheClient:
                 self.client_stats.degraded_reads += len(degraded_idx)
         finally:
             self.guard.release_all()
-        for out in outs:
-            if out.prefetches:
-                self.executor.submit(out.prefetches, now)
+        with span("igt.client.submit"):
+            for out in outs:
+                if out.prefetches:
+                    self.executor.submit(out.prefetches, now)
         want = self.fetch_bytes if fetch is None else fetch
         if not want:
             return [ReadResult(out) for out in outs]
@@ -915,14 +954,16 @@ class CacheClient:
         batched ``fetch_many`` (synthesized/served by the backing store —
         the repo carries no block payload store), deduped across plans
         and against already-demand-fetched ranges."""
-        local: List[RangeRequest] = []
-        for plan in plans:
-            for r, hit in plan:
-                if hit and r not in fetched:
-                    fetched[r] = None  # type: ignore[assignment]  # dedup
-                    local.append(r)
-        if local:
-            fetched.update(zip(local, self.executor.fetch_ranges(local)))
+        with span("igt.client.hits"):
+            local: List[RangeRequest] = []
+            for plan in plans:
+                for r, hit in plan:
+                    if hit and r not in fetched:
+                        fetched[r] = None  # type: ignore[assignment]
+                        local.append(r)
+            if local:
+                fetched.update(zip(local,
+                                   self.executor.fetch_ranges(local)))
 
     def _assemble(self, plan: List[Tuple[RangeRequest, bool]],
                   fetched: Dict[RangeRequest, np.ndarray]) -> np.ndarray:

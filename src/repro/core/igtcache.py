@@ -49,6 +49,7 @@ from .allocation import (FluidAllocator, QuiverAllocator, Rebalancer,
 from .cache import (CacheManageUnit, SubStream, UnifiedCache, path_key)
 from .eviction import EagerEviction
 from .meta import LevelCache, StoreMeta
+from .obs import span
 from .prefetch import (block_sequential_candidates, sequential_candidates,
                        statistical_candidates)
 from .types import (CacheConfig, CacheStats, PathT, Pattern, block_key,
@@ -739,17 +740,21 @@ class IGTCache:
         alloc = self.options.allocation
         if alloc == "adaptive":
             if self.rebalancer.due(now):
-                self.rebalancer.rebalance(list(self.cache.cmus.values()), now)
+                with span("igt.kernel.rebalance"):
+                    self.rebalancer.rebalance(list(self.cache.cmus.values()),
+                                              now)
         elif alloc == "quiver":
             if self.quiver.due(now):
-                self.quiver.rebalance(self.workload_cmus(), now,
-                                      self._workload_capacity())
-                self._give_rest_to_default()
+                with span("igt.kernel.rebalance"):
+                    self.quiver.rebalance(self.workload_cmus(), now,
+                                          self._workload_capacity())
+                    self._give_rest_to_default()
         elif alloc == "fluid":
             if self.fluid.due(now):
-                self.fluid.rebalance(self.workload_cmus(), now,
-                                     self._workload_capacity())
-                self._give_rest_to_default()
+                with span("igt.kernel.rebalance"):
+                    self.fluid.rebalance(self.workload_cmus(), now,
+                                         self._workload_capacity())
+                    self._give_rest_to_default()
         if self._placement_hook is not None:
             self._emit_placement(now)
 

@@ -12,7 +12,7 @@ Prefetch transport is the client's executor: the per-shard
 ``ThreadedExecutor`` for real training runs (background workers fetch
 candidate bytes and complete them on the kernel; overflow/shutdown
 *cancels* candidates instead of dropping them, and both outcomes are
-visible in :class:`PipelineStats`), or the deterministic inline
+counted in the executor's ``ExecutorStats``), or the deterministic inline
 ``SimExecutor`` when ``background_prefetch=False`` (tests, virtual-clock
 callers).
 
@@ -31,6 +31,7 @@ from typing import Dict, Iterator, Optional, Union
 import numpy as np
 
 from ..core.client import CacheClient, SimExecutor, ThreadedExecutor
+from ..core.obs import span
 from ..core.sharded import Engine
 from ..storage.datasets import DatasetSpec, make_dataset
 from ..storage.object_store import RemoteStore
@@ -54,12 +55,6 @@ class PipelineStats:
     bytes_read: int = 0
     cache_hits: int = 0
     cache_misses: int = 0
-    # executor-side candidate accounting (the old PrefetchWorker lost
-    # overflow cancels silently; now every candidate is either completed
-    # or cancelled, and both show up here)
-    prefetch_submitted: int = 0
-    prefetch_completed: int = 0
-    prefetch_cancelled: int = 0
 
     @property
     def hit_ratio(self) -> float:
@@ -91,10 +86,6 @@ class CachedTokenPipeline:
                                       executor=executor)
             self._own_client = True
         self.engine = self.client.engine
-        # per-pipeline attribution on a possibly shared client: report
-        # executor counters as deltas from this construction point
-        ex = self.client.executor.stats
-        self._ex_base = (ex.submitted, ex.completed, ex.cancelled)
         self.dataset = store.datasets[dataset]
         self.seq_len = seq_len
         self.batch = batch
@@ -112,20 +103,12 @@ class CachedTokenPipeline:
             for i in range(n):
                 self._samples.append((f.path, i * self.sample_bytes))
 
-    def _sync_prefetch_stats(self) -> None:
-        ex = self.client.executor.stats
-        base = self._ex_base
-        self.stats.prefetch_submitted = ex.submitted - base[0]
-        self.stats.prefetch_completed = ex.completed - base[1]
-        self.stats.prefetch_cancelled = ex.cancelled - base[2]
-
     def _tokens(self, res) -> np.ndarray:
         """Account one sample's read and decode its tokens."""
         blocks = res.outcome.blocks
         self.stats.cache_hits += sum(1 for b in blocks if b.hit)
         self.stats.cache_misses += sum(1 for b in blocks if not b.hit)
         self.stats.bytes_read += res.data.nbytes
-        self._sync_prefetch_stats()
         return decode_tokens(res.data, self.seq_len + 1, self.vocab)
 
     def batches(self, epochs: int = 1) -> Iterator[Dict[str, np.ndarray]]:
@@ -135,14 +118,16 @@ class CachedTokenPipeline:
                 self.rng.shuffle(order)
             for i in range(0, len(order) - self.batch + 1, self.batch):
                 group = [self._samples[j] for j in order[i:i + self.batch]]
-                now = time.monotonic()
-                # batched client path: the whole training batch goes
-                # through the kernel in one call (tick cadence amortized
-                # per batch); prefetch dispatch is the executor's job
-                results = self.client.read_batch(
-                    [(fp, off, self.sample_bytes) for fp, off in group], now,
-                    fetch=True)
-                arr = np.stack([self._tokens(res) for res in results])
+                with span("igt.pipeline.batch"):
+                    now = time.monotonic()
+                    # batched client path: the whole training batch goes
+                    # through the kernel in one call (tick cadence
+                    # amortized per batch); prefetch dispatch is the
+                    # executor's job
+                    results = self.client.read_batch(
+                        [(fp, off, self.sample_bytes) for fp, off in group],
+                        now, fetch=True)
+                    arr = np.stack([self._tokens(res) for res in results])
                 self.stats.batches += 1
                 yield {"tokens": arr[:, :-1], "labels": arr[:, 1:]}
 
@@ -154,4 +139,3 @@ class CachedTokenPipeline:
     def close(self) -> None:
         if self._own_client:
             self.client.close()
-        self._sync_prefetch_stats()

@@ -392,11 +392,11 @@ def test_pipeline_stats_expose_cancelled_vs_completed():
     pipe.flush(timeout=10.0)
     client.close()
     pipe.close()
-    s = pipe.stats
-    assert s.prefetch_submitted > 0, "sequential scan issued no candidates"
-    assert s.prefetch_cancelled > 0, \
+    s = client.executor.stats
+    assert s.submitted > 0, "sequential scan issued no candidates"
+    assert s.cancelled > 0, \
         "depth-1 queue behind a stalled worker must overflow-cancel"
-    assert s.prefetch_completed + s.prefetch_cancelled <= s.prefetch_submitted
+    assert s.completed + s.cancelled <= s.submitted
     assert not engine._pending_prefetch    # nothing silently dropped
 
 

@@ -30,8 +30,8 @@ the kernel through the client with a link-backed executor; see
     (``storage.api.BackingStore``: ``fetch_range`` / ``fetch_many`` /
     ``capabilities``) that supplies actual bytes — partial-extent reads
     fetch exact sub-block ranges instead of over-fetching whole blocks,
-    and batched reads funnel their demand misses through one
-    ``fetch_many`` call; legacy one-method ``fetch_block`` stores are
+    and batched reads funnel their demand misses through one executor
+    ``fetch_demand`` call; legacy one-method ``fetch_block`` stores are
     adapted transparently (``storage.api.as_backing_store``);
   * a :class:`RetryPolicy`-guarded fetch path: transient store errors
     (``storage.api.TransientStoreError``) retry with bounded backoff,
@@ -113,12 +113,16 @@ class ExecutorStats:
     demand_fetches: int = 0   # priority demand-miss range fetches served
     retries: int = 0          # transient store errors absorbed by RetryPolicy
     fetch_errors: int = 0     # fetches that failed past the retry bound
+    demand_batches: int = 0   # per-shard demand batches a worker served
+    demand_slices: int = 0    # store calls (fetch_ranges) those batches made
 
     def snapshot(self) -> dict:
         return {"submitted": self.submitted, "completed": self.completed,
                 "cancelled": self.cancelled, "deduped": self.deduped,
                 "demand_fetches": self.demand_fetches,
-                "retries": self.retries, "fetch_errors": self.fetch_errors}
+                "retries": self.retries, "fetch_errors": self.fetch_errors,
+                "demand_batches": self.demand_batches,
+                "demand_slices": self.demand_slices}
 
 
 @dataclass
@@ -314,10 +318,11 @@ class NullExecutor(PrefetchExecutor):
 
 
 class _DemandBatch:
-    """One shard's slice of a demand fetch: served by that shard's worker
-    in a single ``fetch_many`` call (shard-parallel batched fetches).
-    ``started`` is set when the worker takes the batch, ``event`` when it
-    is done."""
+    """Demand ranges a reader is blocked on: one shard's part of a demand
+    fetch, which that shard's worker serves in up to the store's
+    ``concurrency`` overlapping slices (``ThreadedExecutor._fetch_batch``),
+    or one such slice handed to a fetch helper.  ``started`` is set when
+    a thread takes it, ``event`` when it is done."""
 
     __slots__ = ("requests", "results", "error", "started", "event")
 
@@ -430,9 +435,15 @@ class ThreadedExecutor(PrefetchExecutor):
     (``cancel_prefetch``) so the pending-table never leaks, and shutdown
     cancels everything still queued.  Demand-miss fetches jump every
     queue (strict priority), are never rejected, and arrive as per-shard
-    batches served in one ``fetch_many`` call each.  Background fetches
-    ride the client's :class:`RetryPolicy`; a fetch that still fails is
-    cancelled on the kernel — the worker survives a failing backend.
+    batches.  A batch of ``n`` ranges is cut into ``min(n, c)`` contiguous
+    slices, ``c`` being the backing store's declared
+    ``capabilities().concurrency``: the worker fetches one slice and a
+    shared pool of ``c - 1`` fetch helpers (``igt-fetch-<i>``) the others
+    at the same time, so the request latencies of one batch overlap.  At
+    ``c == 1`` the worker makes one ``fetch_many`` call over the batch.
+    Background fetches ride the client's :class:`RetryPolicy`; a fetch
+    that still fails is cancelled on the kernel — the worker survives a
+    failing backend.
     """
 
     def __init__(self, queue_depth: int = 4096,
@@ -442,8 +453,13 @@ class ThreadedExecutor(PrefetchExecutor):
         self.queue_depth = queue_depth
         self.max_fetch_bytes = max_fetch_bytes
         self.poll_s = poll_s
+        self.fan_out = 1                    # the store's declared concurrency
         self._queues: List[_ShardQueue] = []
         self._workers: List[threading.Thread] = []
+        self._helpers: List[threading.Thread] = []
+        self._slices: Deque[_DemandBatch] = deque()
+        self._slices_cv = threading.Condition()
+        self._slices_closed = False
         self._stop = threading.Event()
         self._started = False
         self._closed = False
@@ -455,6 +471,9 @@ class ThreadedExecutor(PrefetchExecutor):
         if self._started:
             return
         self._started = True
+        caps = getattr(backing, "capabilities", None)
+        if callable(caps):
+            self.fan_out = max(1, int(caps().concurrency))
         for sid in range(guard.n_shards):
             q = _ShardQueue(self.queue_depth)
             w = threading.Thread(target=self._run, args=(sid, q),
@@ -462,6 +481,11 @@ class ThreadedExecutor(PrefetchExecutor):
             self._queues.append(q)
             self._workers.append(w)
             w.start()
+        for i in range(self.fan_out - 1):
+            h = threading.Thread(target=self._serve_slices,
+                                 name=f"igt-fetch-{i}", daemon=True)
+            self._helpers.append(h)
+            h.start()
 
     def close(self, cancel_pending: bool = True) -> None:
         self._closed = True             # submit() now raises, not enqueues
@@ -476,6 +500,19 @@ class ThreadedExecutor(PrefetchExecutor):
         self._stop.set()
         for w in self._workers:
             w.join(timeout=2.0)
+        # the helpers finish the slices handed to them, then exit; a slice
+        # still queued after the join fails, so its worker cannot hang
+        with self._slices_cv:
+            self._slices_closed = True
+            self._slices_cv.notify_all()
+        for h in self._helpers:
+            h.join(timeout=2.0)
+        with self._slices_cv:
+            stranded = list(self._slices)
+            self._slices.clear()
+        for s in stranded:
+            s.fail(RuntimeError(
+                "ThreadedExecutor closed with the fetch in queue"))
         # workers are down: anything that slipped between drain and join is
         # cancelled too — a candidate must never be dropped silently —
         # and stranded demand waiters are released with an error
@@ -541,11 +578,12 @@ class ThreadedExecutor(PrefetchExecutor):
     def fetch_demand(self, requests: Sequence[RangeRequest]
                      ) -> List[np.ndarray]:
         """Split the demand ranges by shard, hand each shard worker its
-        slice as one priority batch (served via a single ``fetch_many``),
-        and block until every slice lands — misses of one read/batch
-        fetch shard-parallel.  The wait is two spans: until every worker
-        has taken its slice (``igt.client.demand_queued``), then until
-        every slice is done (``igt.client.demand_fetch``)."""
+        part as one priority batch (fetched in up to ``fan_out``
+        overlapping slices, ``_fetch_batch``), and block until every
+        batch lands — misses of one read/batch fetch shard-parallel.  The
+        wait is two spans: until every worker has taken its batch
+        (``igt.client.demand_queued``), then until every batch is done
+        (``igt.client.demand_fetch``)."""
         assert self.backing is not None, "demand fetch needs a backing store"
         with self._stats_lock:
             self.stats.demand_fetches += len(requests)
@@ -587,7 +625,7 @@ class ThreadedExecutor(PrefetchExecutor):
                 # transient errors per the RetryPolicy)
                 try:
                     with span("igt.executor.demand"):
-                        got.results = self.fetch_ranges(got.requests)
+                        got.results = self._fetch_batch(got.requests)
                 except BaseException as e:
                     got.error = e
                 finally:
@@ -601,6 +639,57 @@ class ThreadedExecutor(PrefetchExecutor):
                     self._complete_background(sid, path, size)
             finally:
                 q.task_done(key)
+
+    def _fetch_batch(self, requests: List[RangeRequest]
+                     ) -> List[np.ndarray]:
+        """Fetch one demand batch in ``k = min(n, fan_out)`` contiguous
+        slices, in request order: the calling worker fetches the first,
+        the fetch helpers the other ``k - 1`` at the same time.  Returns
+        after every slice has landed or failed; the first failed slice's
+        error is raised (each slice retried on its own)."""
+        k = min(len(requests), self.fan_out)
+        with self._stats_lock:
+            self.stats.demand_batches += 1
+            self.stats.demand_slices += k
+        if k <= 1:
+            return self.fetch_ranges(requests)
+        cuts = [len(requests) * i // k for i in range(k + 1)]
+        handed = [_DemandBatch(requests[a:b])
+                  for a, b in zip(cuts[1:-1], cuts[2:])]
+        with self._slices_cv:
+            if self._slices_closed:
+                for s in handed:
+                    s.fail(RuntimeError(
+                        "demand fetch on a closed ThreadedExecutor"))
+            else:
+                self._slices.extend(handed)
+                self._slices_cv.notify(len(handed))
+        try:
+            out = list(self.fetch_ranges(requests[:cuts[1]]))
+        finally:
+            for s in handed:
+                s.event.wait()
+        for s in handed:
+            if s.error is not None:
+                raise s.error
+            out.extend(s.results)
+        return out
+
+    def _serve_slices(self) -> None:
+        """Fetch helper: serve handed slices until ``close``."""
+        while True:
+            with self._slices_cv:
+                while not self._slices and not self._slices_closed:
+                    self._slices_cv.wait()
+                if not self._slices:
+                    return
+                s = self._slices.popleft()
+            try:
+                s.results = self.fetch_ranges(s.requests)
+            except BaseException as e:    # handed to the blocked worker
+                s.error = e
+            finally:
+                s.event.set()
 
     def _complete_background(self, sid: int, path: PathT, size: int) -> None:
         """Fetch one background candidate and complete it on the kernel,
@@ -677,7 +766,8 @@ class CacheClient:
     guard, hand the kernel's prefetch candidates to the executor, and —
     when asked for bytes — fetch hits locally (exact sub-block ranges)
     and misses through the executor's priority demand path
-    (shard-parallel ``fetch_many`` batches under the ThreadedExecutor).
+    (shard-parallel batches under the ThreadedExecutor, each fetched in
+    up to the store's ``concurrency`` overlapping ``fetch_many`` slices).
     All kernel introspection (``stats``, ``snapshot``,
     ``iter_workload_cmus``) passes through.
 
@@ -800,9 +890,10 @@ class CacheClient:
         """One kernel ``read_batch`` (tick amortized per batch), prefetch
         dispatch per outcome — and, when fetching bytes, *all* demand
         misses of the batch funneled through one ``fetch_demand`` call
-        (one ``fetch_many`` per shard under the ThreadedExecutor).  When
-        a shard is down only its sub-batch degrades to direct store
-        fetches; the surviving shards' outcomes are kept as-is."""
+        (up to ``concurrency`` ``fetch_many`` slices per shard under the
+        ThreadedExecutor).  When a shard is down only its sub-batch
+        degrades to direct store fetches; the surviving shards' outcomes
+        are kept as-is."""
         with span("igt.client.read_batch"):
             return self._read_batch(requests, now, fetch)
 

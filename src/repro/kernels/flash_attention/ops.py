@@ -1,5 +1,7 @@
 """Dispatching wrapper: Pallas on TPU, jnp oracle elsewhere (CPU dry-run &
-tests).  The two paths are numerically cross-checked in
+tests).  On a TPU the forward and the backward (dq, dk, dv) are both Pallas
+kernels (``kernel.py``); elsewhere the gradient is autodiff through the
+oracle.  The two paths, gradients included, are numerically cross-checked in
 tests/test_kernels.py (interpret=True)."""
 from __future__ import annotations
 

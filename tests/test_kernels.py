@@ -186,9 +186,8 @@ def _grad_case(name):
 @pytest.mark.parametrize("name", ["rmsnorm", "flash_causal", "flash_cross",
                                   "ssd_chunk"])
 def test_pallas_grad_matches_reference(name):
-    """jax.grad through each Pallas kernel (custom_vjp: kernel forward,
-    reference backward) equals jax.grad of its jnp oracle, for every
-    differentiable input."""
+    """jax.grad through each Pallas kernel (its custom_vjp) equals jax.grad
+    of its jnp oracle, for every differentiable input."""
     args, kernel, ref = _grad_case(name)
     argnums = tuple(range(len(args)))
     got = jax.grad(lambda *a: _cotangent_loss(kernel(*a), 0), argnums)(*args)
@@ -197,3 +196,54 @@ def test_pallas_grad_matches_reference(name):
         assert g.shape == w.shape and g.dtype == w.dtype
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-4, atol=1e-4)
+
+
+# name: (B, Sq, Skv, H, KV, hd, block_q, block_kv, causal, q_offset)
+# Several q and kv blocks, so that the causal skip and the clamped index
+# maps are crossed; uneven blocks; KV lengths that need padding.
+FLASH_BWD_CASES = {
+    "causal_mha": (1, 256, 256, 2, 2, 64, 64, 64, True, 0),
+    "causal_gqa2": (2, 256, 256, 4, 2, 64, 64, 64, True, 0),
+    "causal_gqa2_bq32": (1, 256, 256, 4, 2, 64, 32, 64, True, 0),
+    "causal_gqa2_bk32": (1, 256, 256, 4, 2, 64, 64, 32, True, 0),
+    "causal_gqa2_offset_padded": (1, 128, 176, 4, 2, 64, 64, 64, True, 48),
+    "cross_mha": (1, 128, 192, 2, 2, 64, 64, 64, False, 0),
+    "cross_gqa2_padded": (1, 128, 100, 4, 2, 64, 64, 64, False, 0),
+}
+# bfloat16 keeps 8 significant bits: one rounding moves a value by up to
+# 2^-9 of itself.  The kernel rounds P and dS before their products and
+# its gradients once more at the end, and the oracle rounds its float32
+# gradients once, so a few 2^-8 of the largest gradient is rounding (the
+# cases read up to 7.2e-3).  2e-2 of it, about five 2^-8, leaves room for
+# sums over a few hundred keys; a wrong mask or a skipped block moves
+# gradients by tens of percent of the largest.
+FLASH_BWD_TOL = {jnp.float32: 1e-4, jnp.bfloat16: 2e-2}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", sorted(FLASH_BWD_CASES))
+def test_flash_pallas_backward(name, dtype):
+    """dq, dk and dv from the Pallas backward kernels (interpret mode)
+    equal jax.grad of the jnp oracle: float32 at 1e-4, bfloat16 at
+    FLASH_BWD_TOL of the largest gradient."""
+    B, Sq, Skv, H, KV, hd, bq, bk, causal, q_offset = FLASH_BWD_CASES[name]
+    rng = np.random.default_rng(11)
+    args = tuple(jnp.asarray(rng.normal(size=s), dtype)
+                 for s in ((B, Sq, H, hd), (B, Skv, KV, hd),
+                           (B, Skv, KV, hd)))
+    kw = dict(causal=causal, q_offset=q_offset)
+    got = jax.grad(lambda *a: _cotangent_loss(flash_attention_pallas(
+        *a, block_q=bq, block_kv=bk, interpret=True, **kw), 0),
+        (0, 1, 2))(*args)
+    want = jax.grad(lambda *a: _cotangent_loss(flash_attention_ref(
+        *a, block_kv=bk, **kw), 0), (0, 1, 2))(*args)
+    tol = FLASH_BWD_TOL[dtype]
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g, w, rtol=tol, atol=tol)
+        else:
+            np.testing.assert_allclose(g, w, rtol=0,
+                                       atol=tol * np.abs(w).max())

@@ -11,6 +11,8 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU compiler library, and every test
 worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -83,3 +85,38 @@ def test_kernel_compiles_for_v5e(case, mode, one_chip):
         fn = jax.value_and_grad(loss, argnums=tuple(range(len(args))))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _custom_call_results(hlo: str) -> list:
+    """Result shape of each ``tpu_custom_call`` in an HLO text, layouts
+    dropped: ``bf16[2,16,2048,128]`` or a tuple ``(f32[..], ..)``."""
+    out = []
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            line = re.sub(r"\{[^{}]*\}", "", line)
+            out.append(re.search(r" = (.+?) custom-call\(", line).group(1))
+    return out
+
+
+def test_flash_backward_is_pallas_for_v5e(one_chip):
+    """At qwen3-1.7b's per-chip widths, value_and_grad through the flash
+    kernel compiles to Pallas calls for the backward too: no float32
+    (.., 2048, 512) score blocks of the jnp oracle's VJP, and exactly one
+    call whose single result is the attention output bf16[2,16,2048,128]
+    (the forward), so a reader that finds the forward by that result
+    counts no backward call."""
+    _, shapes = CASES["flash_qwen3"]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+
+    def loss(q, k, v):
+        return flash_attention_pallas(q, k, v).astype(F32).sum()
+
+    hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    results = _custom_call_results(hlo)
+    assert results.count("bf16[2,16,2048,128]") == 1, results
+    backward = [r for r in results if r != "bf16[2,16,2048,128]"]
+    assert any("bf16[2,8,2048,128]" in r for r in backward), results  # dk, dv
+    assert "f32[2,16,2048,128]" in backward, results                  # dq
+    assert not re.search(r"f32\[(\d+,)*2048,512\]", hlo)

@@ -3,9 +3,7 @@
 The daemon's scale-out claim: the serve path adds one framed round-trip
 per batch but removes the per-process kernel, so N client *processes*
 sharing one daemon should deliver aggregate metadata throughput that
-scales with N — past the single-client multi-process driver number
-(``proc_4`` in ``BENCH_overhead.json``), which pays RPC fan-out per
-batch without any cross-process sharing to show for it.
+scales with N.
 
 Protocol: one ``CacheDaemon`` on a temp UDS over a seeded RemoteStore
 world; for N in {1, 2, 4}, fork N client processes that each
@@ -29,7 +27,6 @@ working set over several.
 from __future__ import annotations
 
 import argparse
-import json
 import multiprocessing as mp
 import tempfile
 import time
@@ -37,7 +34,7 @@ import time
 import numpy as np
 
 # .common bootstraps sys.path with REPO_ROOT/src — must import before repro
-from .common import REPO_ROOT, csv_row, merge_overhead_section
+from .common import csv_row, merge_overhead_section
 
 from repro.core import CacheConfig, open_cache
 from repro.core.types import MB
@@ -100,15 +97,6 @@ def _measure(uri, files, n_clients, n_steps, batch, seed):
             "us_per_access": round(wall / total * 1e6, 1)}
 
 
-def _proc4_reference():
-    """The single-client 4-worker number this axis must scale past."""
-    try:
-        payload = json.loads((REPO_ROOT / "BENCH_overhead.json").read_text())
-        return payload["proc_path"]["proc_4"]["us_per_access"]
-    except (OSError, KeyError, ValueError):
-        return None
-
-
 def main(smoke: bool = False, seed: int = 0, json_path=None):
     n_steps = 8 if smoke else 64
     batch = 8 if smoke else 64
@@ -132,12 +120,6 @@ def main(smoke: bool = False, seed: int = 0, json_path=None):
     r1 = section["daemon_1"]["accesses_per_s"]
     r4 = section["daemon_4"]["accesses_per_s"]
     section["scaling_4_vs_1"] = round(r4 / r1, 2)
-    proc4_us = _proc4_reference()
-    section["proc_4_reference_us"] = proc4_us
-    if proc4_us:
-        # aggregate daemon throughput vs the single-client proc_4 rate
-        section["daemon_4_vs_proc_4"] = round(
-            r4 / (1e6 / proc4_us), 2)
 
     rows = [
         csv_row("daemon_path.daemon_1_accesses_per_s", r1,
@@ -146,9 +128,6 @@ def main(smoke: bool = False, seed: int = 0, json_path=None):
                 f"us_per_access={section['daemon_4']['us_per_access']}"),
         csv_row("daemon_path.scaling_4_vs_1", section["scaling_4_vs_1"],
                 f"daemon_2={section['daemon_2']['accesses_per_s']}"),
-        csv_row("daemon_path.daemon_4_vs_proc_4",
-                section.get("daemon_4_vs_proc_4"),
-                f"proc_4_us={proc4_us}"),
     ]
     merge_overhead_section("daemon_path", section, json_path)
     return rows

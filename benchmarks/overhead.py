@@ -21,15 +21,6 @@ the scaled paper-suite cluster sim per shard count under both
 ``quantum_policy`` settings, recording CHR gap vs unsharded, summary
 bytes/round shipped by the sketch-based demand summaries, and
 rounds-to-converge (the round after which the planner goes quiet).
-
-``--procs 1,2,4`` (the default) measures the **multi-process shard
-driver** (``core.procdriver.ProcessShardedCache``) on a batched
-whole-sample ``read_batch`` protocol (steady-state: untimed warmup
-prefix), always alongside the single-process kernel loop and the
-in-process 4-shard facade, all interleaved run-by-run; the points land
-in the JSON's ``proc_path`` section via ``merge_overhead_section`` (the
-headline is ``proc_4`` beating both ``proc_1`` and the in-process
-engines — shard count as an actual throughput knob).
 """
 from __future__ import annotations
 
@@ -42,8 +33,7 @@ from pathlib import Path
 
 import numpy as np
 
-from repro.core import (CacheConfig, IGTCache, ProcessShardedCache,
-                        ShardedIGTCache)
+from repro.core import CacheConfig, IGTCache, ShardedIGTCache
 from repro.core.types import MB
 from repro.storage import RemoteStore, make_dataset
 
@@ -164,116 +154,8 @@ def measure_shards(shard_counts, node_cap: int, n_accesses: int,
     return best
 
 
-# ---------------------------------------------------------------------------
-# multi-process shard driver axis (proc_path)
-# ---------------------------------------------------------------------------
-
-def _proc_store():
-    """The 8-dataset layout of the sharded axis (routing is per dataset)."""
-    store = RemoteStore()
-    for i in range(8):
-        store.add(make_dataset(f"ds{i}", "dir_tree", n_dirs=10,
-                               files_per_dir=120, small_file_size=9 * MB))
-    return store
-
-
-def _timed_batch_trace(eng, files, n_accesses: int, seed: int,
-                       batch: int, warmup_frac: float = 0.25) -> float:
-    """The ``read_batch`` measurement protocol shared by every driver on
-    the proc axis: the seeded random 64 KiB trace of ``_timed_trace``,
-    grouped into fixed-size batches, with inline prefetch completion.
-    In-process engines complete the returned candidates here (the
-    caller-driven loop); the process driver runs ``prefetch="inline"``
-    so its workers complete the same candidates kernel-side — the
-    completion loop below then sees empty lists, and the kernel state
-    evolution is identical.
-
-    The first ``warmup_frac`` of the trace runs **untimed** for every
-    configuration: this axis measures *steady-state* throughput of a
-    long-running shard driver, not first-touch costs (tree build,
-    fork/COW page materialization, pickle memo warmup — the process
-    driver pays the latter two once per worker lifetime, the in-process
-    engines never do).  Accesses are **whole-sample reads** (the full
-    9 MB file → a 3-block extent at the 4 MB block size): batched
-    ``read_batch`` traffic is training loaders fetching samples, not
-    sub-block probes — this is the protocol the single-access Fig.-17
-    axis does *not* cover.  Returns µs/access over the timed portion."""
-    rng = np.random.default_rng(seed)
-    idx = rng.integers(0, len(files), n_accesses)
-    reqs = []
-    for j in idx:
-        f = files[int(j)]
-        reqs.append((f.path, 0, f.size))
-    warm = int(n_accesses * warmup_frac) // batch * batch
-
-    def drive(start: int, stop: int) -> None:
-        for s in range(start, stop, batch):
-            now = s * 0.001
-            outs = eng.read_batch(reqs[s:s + batch], now)
-            for out in outs:
-                for p, sz in out.prefetches:
-                    eng.complete_prefetch(p, sz, now)
-
-    gc_was_enabled = gc.isenabled()
-    gc.collect()
-    gc.disable()
-    try:
-        drive(0, warm)                       # untimed warmup, all configs
-        t0 = time.perf_counter()
-        drive(warm, n_accesses)
-        dt = time.perf_counter() - t0
-    finally:
-        if gc_was_enabled:
-            gc.enable()
-    return dt / max(1, n_accesses - warm) * 1e6
-
-
-def _run_once_proc_axis(config, node_cap: int, n_accesses: int, seed: int,
-                        batch: int) -> float:
-    """One timed run of one proc-axis configuration.
-
-    ``config`` is ``("kernel", 1)`` (plain IGTCache — the single-process
-    kernel loop), ``("facade", n)`` (in-process ShardedIGTCache) or
-    ``("proc", n)`` (the multi-process driver, workers GC-paused to
-    match the client-side GC pause of the in-process runs)."""
-    store = _proc_store()
-    cfg = CacheConfig(node_cap=node_cap, min_share=8 * MB,
-                      rebalance_quantum=8 * MB)
-    kind, n = config
-    if kind == "kernel":
-        eng = IGTCache(store, 512 * MB, cfg=cfg)
-    elif kind == "facade":
-        eng = ShardedIGTCache(store, 512 * MB, cfg=cfg, n_shards=n)
-    else:
-        eng = ProcessShardedCache(store, 512 * MB, cfg=cfg, n_procs=n,
-                                  prefetch="inline", pause_worker_gc=True)
-    files = [f for ds in store.datasets.values() for f in ds.files]
-    try:
-        return _timed_batch_trace(eng, files, n_accesses, seed, batch)
-    finally:
-        if kind == "proc":
-            eng.close()
-
-
-def measure_procs(proc_counts, node_cap: int, n_accesses: int, seed: int,
-                  repeats: int, batch: int = 256):
-    """Interleaved same-protocol sweep for the multi-process driver: the
-    single-process kernel loop, the in-process 4-shard facade, and the
-    process driver at each ``--procs`` count all run the identical
-    batched trace back-to-back within each repeat; best per config."""
-    configs = [("kernel", 1), ("facade", 4)] + \
-              [("proc", n) for n in proc_counts]
-    best = {c: None for c in configs}
-    for _ in range(max(1, repeats)):
-        for c in configs:
-            us = _run_once_proc_axis(c, node_cap, n_accesses, seed, batch)
-            if best[c] is None or us < best[c]:
-                best[c] = us
-    return best
-
-
 def main(scale: float = 1.0, seed: int = 0, smoke: bool = False,
-         json_path=None, shard_counts=(1, 4, 8, 16), proc_counts=(1, 2, 4)):
+         json_path=None, shard_counts=(1, 4, 8, 16)):
     caps = (10_000,) if smoke else (100, 1000, 10_000, 100_000)
     n_accesses = 6_000 if smoke else 30_000
     repeats = 2 if smoke else 3
@@ -328,7 +210,7 @@ def main(scale: float = 1.0, seed: int = 0, smoke: bool = False,
     # smoke runs must not clobber the canonical full-sweep record
     name = "overhead_smoke" if smoke else "overhead"
     # ... and a full run must not clobber the axes other benchmarks
-    # merged into the shared file (client_path / store_path / proc_path):
+    # merged into the shared file (client_path / store_path / ...):
     # carry unknown sections over before rewriting
     from .common import REPO_ROOT
     prev_path = (Path(json_path) if json_path is not None
@@ -342,11 +224,6 @@ def main(scale: float = 1.0, seed: int = 0, smoke: bool = False,
             if key not in ("bench", "generated_unix"):
                 payload.setdefault(key, val)
     out_path = emit_json(name, payload, path=json_path)
-    # ---- multi-process driver axis (interleaved, batched protocol) ----
-    if proc_counts:
-        rows.extend(run_proc_axis(tuple(proc_counts), seed=seed,
-                                  smoke=smoke,
-                                  json_path=json_path or out_path))
     # ---- cross-shard rebalance axis (cluster sim, both policies) ------
     reb_counts = tuple(n for n in shard_counts if n > 1)
     if reb_counts:
@@ -429,44 +306,6 @@ def run_rebalance_axis(shard_counts=(4, 8, 16), seed: int = 0,
     return rows
 
 
-def run_proc_axis(proc_counts=(1, 2, 4), seed: int = 0, smoke: bool = False,
-                  json_path=None):
-    """Measure + record the ``proc_path`` section on its own (main()
-    calls this; re-recording the axis does not require re-running the
-    whole Fig.-17 sweep).  More repeats than the other axes: the driver
-    configurations are the most sensitive to the container's CPU
-    weather (4 worker processes on ~1.5 effective cores), and best-of
-    needs samples to find a representative window for every config —
-    interleaving keeps any single run internally fair."""
-    proc_accesses = 1_024 if smoke else 8_192
-    batch = 128 if smoke else 256
-    repeats = 2 if smoke else 4
-    rows = []
-    got = measure_procs(proc_counts, 10_000, proc_accesses,
-                        seed, repeats, batch=batch)
-    section = {"smoke": smoke, "n_accesses": proc_accesses,
-               "repeats": repeats, "batch": batch}
-    for (kind, n), us in got.items():
-        key = "kernel_1" if kind == "kernel" else f"{kind}_{n}"
-        section[key] = {"us_per_access": round(us, 1)}
-        rows.append(csv_row(f"proc_path.{key}.us_per_access",
-                            round(us, 1), "interleaved-batched-protocol"))
-    if "proc_4" in section and "proc_1" in section:
-        section["speedup_4p_vs_1p"] = round(
-            section["proc_1"]["us_per_access"] /
-            section["proc_4"]["us_per_access"], 2)
-        section["speedup_4p_vs_kernel"] = round(
-            section["kernel_1"]["us_per_access"] /
-            section["proc_4"]["us_per_access"], 2)
-        section["speedup_4p_vs_facade"] = round(
-            section["facade_4"]["us_per_access"] /
-            section["proc_4"]["us_per_access"], 2)
-    # lands next to results/sharded/client_path/store_path without
-    # clobbering them (read-modify-write of the shared JSON)
-    merge_overhead_section("proc_path", section, json_path=json_path)
-    return rows
-
-
 if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
@@ -476,14 +315,6 @@ if __name__ == "__main__":
                     help="comma-separated shard counts for the sharded-"
                          "facade axis and (counts >1) the rebalance_path "
                          "axis ('' disables both)")
-    ap.add_argument("--procs", default="1,2,4",
-                    help="comma-separated worker counts for the multi-"
-                         "process driver axis ('' disables it); the "
-                         "single-process kernel loop and the in-process "
-                         "4-shard facade are always measured alongside, "
-                         "interleaved")
     args = ap.parse_args()
     counts = tuple(int(x) for x in args.shards.split(",") if x.strip())
-    procs = tuple(int(x) for x in args.procs.split(",") if x.strip())
-    main(seed=args.seed, smoke=args.smoke, shard_counts=counts,
-         proc_counts=procs)
+    main(seed=args.seed, smoke=args.smoke, shard_counts=counts)

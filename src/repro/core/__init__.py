@@ -17,15 +17,13 @@ from .cache import CacheManageUnit, UnifiedCache, path_key
 from .client import (BackingStore, CacheClient, ClientStats, ExecutorStats,
                      KernelGuard, NullExecutor, PrefetchExecutor, ReadResult,
                      SimExecutor, ThreadedExecutor, open_cache)
-from .faults import (RestartBudget, SHARD_DOWN, SHARD_RESTARTING, SHARD_UP,
-                     ShardUnavailableError)
+from .faults import RestartBudget, SHARD_DOWN, SHARD_RESTARTING, SHARD_UP
 from .igtcache import EngineOptions, IGTCache, ReadOutcome, informative_depth
 from .ks import ks_critical, ks_test_random, triangular_cdf
 from .meta import LevelCache
 from .pattern import (PatternResult, classify, classify_batch,
                       detect_sequential, fit_adaptive_ttl,
                       fit_adaptive_ttl_batch)
-from .procdriver import ProcessExecutor, ProcessShardedCache, ShmArena
 from .sharded import (DemandSummary, GlobalRebalancer, ShardDemandTracker,
                       ShardRouting, ShardedIGTCache, make_engine,
                       shard_index, split_capacity)
@@ -40,11 +38,9 @@ __all__ = [
     "GlobalRebalancer", "IGTCache", "KernelGuard", "LevelCache", "MB",
     "NullExecutor", "ObservedChain",
     "PathT", "Pattern", "PatternResult", "PrefetchExecutor",
-    "ProcessExecutor", "ProcessShardedCache", "ReadOutcome",
-    "ReadResult", "RestartBudget", "SHARD_DOWN", "SHARD_RESTARTING",
-    "SHARD_UP", "ShardDemandTracker", "ShardRouting", "ShardUnavailableError",
-    "ShardedIGTCache",
-    "ShmArena", "SimExecutor", "ThreadedExecutor",
+    "ReadOutcome", "ReadResult", "RestartBudget", "SHARD_DOWN",
+    "SHARD_RESTARTING", "SHARD_UP", "ShardDemandTracker", "ShardRouting",
+    "ShardedIGTCache", "SimExecutor", "ThreadedExecutor",
     "UnifiedCache", "analyze_streams", "block_key", "bundle",
     "bundle_client", "bundle_engine", "classify",
     "classify_batch", "detect_sequential", "fit_adaptive_ttl",

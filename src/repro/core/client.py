@@ -51,9 +51,8 @@ the kernel through the client with a link-backed executor; see
 constructor path all consumers share; ``store`` may be a store instance
 or a URI for the scheme registry (``"sim://default"``,
 ``"file:///data"``, ``"mem://"``, ``"faulty+sim://..."`` — see
-``storage.api.open_store``).  Every future scaling lever (multi-process
-shards, S3/GCS adapters) plugs in behind these two protocols.  See
-docs/API.md for the full contract.
+``storage.api.open_store``).  Every future scaling lever plugs in
+behind these two protocols.  See docs/API.md for the full contract.
 """
 from __future__ import annotations
 
@@ -67,10 +66,9 @@ from typing import (Callable, Deque, Dict, List, Optional, Sequence, Set,
 import numpy as np
 
 from .cache import path_key
-from .faults import SHARD_UP, ShardUnavailableError
-from .igtcache import BlockResult, EngineOptions, ReadOutcome
+from .igtcache import EngineOptions, ReadOutcome
 from .obs import span
-from .sharded import Engine, ShardedIGTCache, make_engine
+from .sharded import Engine, make_engine
 from .types import CacheConfig, PathT, block_key
 
 __all__ = [
@@ -127,23 +125,19 @@ class ExecutorStats:
 
 @dataclass
 class ClientStats:
-    """Degraded-path accounting for one :class:`CacheClient`.
+    """Degraded-path accounting for one ``daemon.RemoteCacheClient``.
 
-    Counts reads the client served *around* the kernel while a shard was
-    down/restarting (bytes came straight from the backing store, no
-    cache observation happened) — the availability cost a fault leaves
-    behind.  ``fallback_fetches`` counts demand fetches that started on
-    the executor and finished on the store after the shard died between
-    the kernel read and the byte fetch."""
+    Counts reads the client served *around* the cache while its daemon
+    was away (bytes came straight from the backing store, no cache
+    observation happened) — the availability cost a fault leaves
+    behind."""
 
     degraded_reads: int = 0       # read requests served without the kernel
     degraded_bytes: int = 0       # bytes fetched via the degraded path
-    fallback_fetches: int = 0     # executor demand fetches re-run direct
 
     def snapshot(self) -> dict:
         return {"degraded_reads": self.degraded_reads,
-                "degraded_bytes": self.degraded_bytes,
-                "fallback_fetches": self.fallback_fetches}
+                "degraded_bytes": self.degraded_bytes}
 
 
 class KernelGuard:
@@ -158,8 +152,7 @@ class KernelGuard:
 
     def __init__(self, engine: Engine) -> None:
         self.engine = engine
-        # duck-typed: any sharded driver (in-process facade or the
-        # multi-process ProcessShardedCache) exposes n_shards + shard_id
+        # duck-typed: a sharded engine exposes n_shards + shard_id
         n = getattr(engine, "n_shards", 1)
         self._locks = [threading.Lock() for _ in range(n)]
         self._sharded = n > 1
@@ -429,15 +422,14 @@ class ThreadedExecutor(PrefetchExecutor):
 
     One daemon worker per kernel shard (``IGTCache`` counts as one
     shard); a candidate is routed to its block's shard worker, so
-    completions only ever contend with reads of the same shard — the
-    multi-worker shard driver from the ROADMAP.  Per-shard queues are
-    bounded; an overflowing candidate is *cancelled on the kernel*
-    (``cancel_prefetch``) so the pending-table never leaks, and shutdown
-    cancels everything still queued.  Demand-miss fetches jump every
-    queue (strict priority), are never rejected, and arrive as per-shard
-    batches.  A batch of ``n`` ranges is cut into ``min(n, c)`` contiguous
-    slices, ``c`` being the backing store's declared
-    ``capabilities().concurrency``: the worker fetches one slice and a
+    completions only ever contend with reads of the same shard.
+    Per-shard queues are bounded; an overflowing candidate is *cancelled
+    on the kernel* (``cancel_prefetch``) so the pending-table never
+    leaks, and shutdown cancels everything still queued.  Demand-miss
+    fetches jump every queue (strict priority), are never rejected, and
+    arrive as per-shard batches.  A batch of ``n`` ranges is cut into
+    ``min(n, c)`` contiguous slices, ``c`` being the backing store's
+    declared ``capabilities().concurrency``: the worker fetches one slice and a
     shared pool of ``c - 1`` fetch helpers (``igt-fetch-<i>``) the others
     at the same time, so the request latencies of one batch overlap.  At
     ``c == 1`` the worker makes one ``fetch_many`` call over the batch.
@@ -775,19 +767,9 @@ class CacheClient:
     understands: a v2 store, a legacy one-method ``fetch_block`` store
     (adapted), or ``None`` for metadata-only clients.
 
-    **Degraded-mode reads** (``degraded=True``, the default): when the
-    kernel raises :class:`ShardUnavailableError` — a shard worker of the
-    multi-process driver died, is restarting, or exhausted its restart
-    budget — the client serves the affected requests *around* the
-    kernel: it synthesizes an all-miss outcome from the store's file
-    geometry and fetches the bytes straight from the backing store, so
-    callers always get correct bytes and never hang on a dead worker.
-    Only the failed sub-batch degrades; outcomes the surviving shards
-    already produced are kept (re-reading would double-observe their
-    keys).  Degraded traffic is counted in :class:`ClientStats`.  The
-    only error a reader sees is the backing store itself permanently
-    failing.  ``breaker`` (a ``storage.api.CircuitBreaker``) optionally
-    guards every client-side byte fetch against a store that is failing
+    The only error a reader sees is the backing store itself failing
+    past the retry bound.  ``breaker`` (a ``storage.api.CircuitBreaker``)
+    optionally guards every byte fetch against a store that is failing
     hard: after K consecutive transient failures calls fast-fail with
     ``CircuitOpenError`` until the breaker half-opens.
 
@@ -801,7 +783,6 @@ class CacheClient:
                  clock: Optional[Callable[[], float]] = None,
                  fetch_bytes: bool = False,
                  retry=None,
-                 degraded: bool = True,
                  breaker=None) -> None:
         from ..storage.api import RetryPolicy, as_backing_store
         self.engine = engine
@@ -820,9 +801,6 @@ class CacheClient:
             self.retry = RetryPolicy(breaker=breaker)
         else:
             self.retry = RetryPolicy()
-        self.degraded = degraded
-        self.client_stats = ClientStats()
-        self._cstats_lock = threading.Lock()
         self.clock = clock or time.monotonic
         self.guard = KernelGuard(engine)
         self.executor = executor if executor is not None else SimExecutor()
@@ -832,18 +810,13 @@ class CacheClient:
         if fetch_bytes and self.backing is None:
             raise ValueError("fetch_bytes=True needs a backing store")
         self._closed = False
-        # open_cache sets this: a client that *constructed* its engine
-        # also shuts it down (process-backed drivers own OS resources)
-        self._own_engine = False
 
     # ------------------------------------------------------------------ read
     def read(self, file_path: PathT, offset: int, size: int,
              now: Optional[float] = None, *,
              fetch: Optional[bool] = None) -> ReadResult:
         """Serve one extent: kernel read → executor-dispatched prefetch →
-        (optionally) bytes for the requested range.  A dead shard
-        degrades to a direct store fetch instead of raising (see the
-        class docstring)."""
+        (optionally) bytes for the requested range."""
         with span("igt.client.read"):
             return self._read(file_path, offset, size, now, fetch)
 
@@ -851,23 +824,14 @@ class CacheClient:
               now: Optional[float], fetch: Optional[bool]) -> ReadResult:
         if now is None:
             now = self.clock()
-        degraded = False
         lock = self.guard.lock_for(file_path)
+        with span("igt.kernel.lock_wait"):
+            lock.acquire()
         try:
-            with span("igt.kernel.lock_wait"):
-                lock.acquire()
-            try:
-                with span("igt.kernel.read"):
-                    out = self.engine.read(file_path, offset, size, now)
-            finally:
-                lock.release()
-        except ShardUnavailableError:
-            if not self.degraded:
-                raise
-            out = self._degraded_outcome(file_path, offset, size)
-            degraded = True
-            with self._cstats_lock:
-                self.client_stats.degraded_reads += 1
+            with span("igt.kernel.read"):
+                out = self.engine.read(file_path, offset, size, now)
+        finally:
+            lock.release()
         if out.prefetches:
             with span("igt.client.submit"):
                 self.executor.submit(out.prefetches, now)
@@ -879,8 +843,7 @@ class CacheClient:
         fetched: Dict[RangeRequest, np.ndarray] = {}
         demand = [r for r, hit in plan if not hit]
         if demand:
-            fetched.update(zip(demand,
-                               self._fetch_misses(demand, degraded)))
+            fetched.update(zip(demand, self.executor.fetch_demand(demand)))
         self._fetch_hits([plan], fetched)
         return ReadResult(out, self._assemble(plan, fetched))
 
@@ -891,9 +854,7 @@ class CacheClient:
         dispatch per outcome — and, when fetching bytes, *all* demand
         misses of the batch funneled through one ``fetch_demand`` call
         (up to ``concurrency`` ``fetch_many`` slices per shard under the
-        ThreadedExecutor).  When a shard is down only its sub-batch
-        degrades to direct store fetches; the surviving shards' outcomes
-        are kept as-is."""
+        ThreadedExecutor)."""
         with span("igt.client.read_batch"):
             return self._read_batch(requests, now, fetch)
 
@@ -903,28 +864,11 @@ class CacheClient:
         if now is None:
             now = self.clock()
         requests = list(requests)
-        degraded_idx: Set[int] = set()
         with span("igt.kernel.lock_wait"):
             self.guard.acquire_all()
         try:
             with span("igt.kernel.read"):
                 outs = self.engine.read_batch(requests, now)
-        except ShardUnavailableError as e:
-            if not self.degraded:
-                raise
-            # patch only the holes: the error carries the healthy
-            # shards' outcomes, and re-issuing them would double-observe
-            partial = (e.partial if e.partial is not None
-                       else [None] * len(requests))
-            holes = (e.indices if e.indices is not None
-                     else [i for i, o in enumerate(partial) if o is None])
-            outs = list(partial)
-            for i in holes:
-                fp, off, sz = requests[i]
-                outs[i] = self._degraded_outcome(fp, off, sz)
-                degraded_idx.add(i)
-            with self._cstats_lock:
-                self.client_stats.degraded_reads += len(degraded_idx)
         finally:
             self.guard.release_all()
         with span("igt.client.submit"):
@@ -937,80 +881,20 @@ class CacheClient:
         self._require_backing()
         plans = [self._plan_ranges(fp, off, sz, out) if out.blocks else []
                  for (fp, off, sz), out in zip(requests, outs)]
-        all_demand: List[RangeRequest] = []
-        direct_demand: List[RangeRequest] = []
+        demand: List[RangeRequest] = []
         seen: Set[RangeRequest] = set()
-        for j, plan in enumerate(plans):
+        for plan in plans:
             for r, hit in plan:
                 if not hit and r not in seen:
                     seen.add(r)
-                    # a degraded request's shard is dead: its misses
-                    # must not travel through the executor's worker RPC
-                    (direct_demand if j in degraded_idx
-                     else all_demand).append(r)
+                    demand.append(r)
         fetched: Dict[RangeRequest, np.ndarray] = {}
-        if all_demand:
-            fetched.update(zip(all_demand,
-                               self._fetch_misses(all_demand, False)))
-        if direct_demand:
-            fetched.update(zip(direct_demand,
-                               self._fetch_misses(direct_demand, True)))
+        if demand:
+            fetched.update(zip(demand, self.executor.fetch_demand(demand)))
         self._fetch_hits(plans, fetched)
         return [ReadResult(out,
                            self._assemble(plan, fetched) if plan else None)
                 for out, plan in zip(outs, plans)]
-
-    # ------------------------------------------------------- degraded path
-    def _degraded_outcome(self, file_path: PathT, offset: int,
-                          size: int) -> ReadOutcome:
-        """All-miss outcome for a request whose shard kernel is gone,
-        built from the store's file geometry (clamped to EOF) — the same
-        block decomposition the kernel would have produced, minus any
-        caching/prefetching (the kernel never saw the access)."""
-        bs = self.engine.cfg.block_size
-        try:
-            fsize = self.engine.meta.file_size(file_path)
-        except Exception:
-            fsize = offset + size    # unknown geometry: trust the request
-        end = min(offset + size, fsize)
-        blocks: List[BlockResult] = []
-        if end > offset:
-            first = offset // bs
-            for b in range(first, (end - 1) // bs + 1):
-                blocks.append(BlockResult(
-                    path_key(block_key(file_path, b)),
-                    min(bs, fsize - b * bs), False))
-        return ReadOutcome(blocks, [])
-
-    def _direct_fetch(self, requests: Sequence[RangeRequest]
-                      ) -> List[np.ndarray]:
-        """Degraded byte path: straight to the backing store, bypassing
-        the executor (whose demand path would RPC the dead worker).
-        Retry-guarded and breaker-guarded like every other fetch."""
-        if self.breaker is not None:
-            data = self.retry.call(self.backing.fetch_many, list(requests),
-                                   breaker=self.breaker)
-        else:   # a caller-supplied policy may not take the breaker kwarg
-            data = self.retry.call(self.backing.fetch_many, list(requests))
-        with self._cstats_lock:
-            self.client_stats.degraded_bytes += sum(r[2] for r in requests)
-        return data
-
-    def _fetch_misses(self, demand: List[RangeRequest],
-                      degraded: bool) -> List[np.ndarray]:
-        """Demand misses via the executor — or, for degraded requests /
-        a shard that died after the kernel read, direct from the store
-        so the blocked reader still gets its bytes."""
-        if degraded:
-            return self._direct_fetch(demand)
-        try:
-            return self.executor.fetch_demand(demand)
-        except ShardUnavailableError:
-            if not self.degraded:
-                raise
-            with self._cstats_lock:
-                self.client_stats.fallback_fetches += 1
-            return self._direct_fetch(demand)
 
     # ------------------------------------------------------------ byte paths
     def _require_backing(self) -> None:
@@ -1129,7 +1013,6 @@ class CacheClient:
     def snapshot(self) -> dict:
         s = self.engine.snapshot()
         s["executor"] = self.executor.stats.snapshot()
-        s["client"] = self.client_stats.snapshot()
         caps = self.store_capabilities()
         if caps is not None:
             s["store"] = {"capabilities": caps.snapshot()}
@@ -1139,23 +1022,6 @@ class CacheClient:
         if callable(tiers):
             s.setdefault("store", {})["tiers"] = tiers()
         return s
-
-    def fault_stats(self) -> dict:
-        """Supervision observability of the underlying driver (shard
-        states, restart budgets, kill/respawn events) plus this client's
-        degraded-path counters.  In-process engines have no failure
-        domains, so their driver section is empty."""
-        fn = getattr(self.engine, "fault_stats", None)
-        got = fn() if fn is not None else {"restarts": 0, "shards": {},
-                                           "events": []}
-        got["client"] = self.client_stats.snapshot()
-        return got
-
-    def shard_states(self) -> List[str]:
-        fn = getattr(self.engine, "shard_states", None)
-        if fn is not None:
-            return fn()
-        return [SHARD_UP] * getattr(self.engine, "n_shards", 1)
 
     def iter_workload_cmus(self):
         return self.engine.iter_workload_cmus()
@@ -1177,19 +1043,12 @@ class CacheClient:
         return self.executor.flush(timeout)
 
     def close(self, cancel_pending: bool = True) -> None:
-        """Shut the executor down (cancelling queued candidates on the
-        kernel), then — when this client constructed its engine
-        (``open_cache``) — the engine itself.  In-process kernels carry
-        no OS resources; the multi-process driver joins its workers and
-        releases the shared-memory arena."""
+        """Shut the executor down, cancelling queued candidates on the
+        kernel.  Idempotent."""
         if self._closed:
             return
         self._closed = True
         self.executor.close(cancel_pending=cancel_pending)
-        if self._own_engine:
-            engine_close = getattr(self.engine, "close", None)
-            if engine_close is not None:
-                engine_close()
 
     def __enter__(self) -> "CacheClient":
         return self
@@ -1198,16 +1057,13 @@ class CacheClient:
         self.close()
 
 
-_EXECUTORS = ("sim", "threaded", "none", "process")
+_EXECUTORS = ("sim", "threaded", "none")
 
 
 def open_cache(store, capacity: Optional[int] = None, *,
                cfg: Optional[CacheConfig] = None,
                options: Optional[EngineOptions] = None,
                n_shards: int = 1,
-               driver: str = "thread",
-               n_procs: Optional[int] = None,
-               arena_bytes: Optional[int] = None,
                executor: Optional[Union[str, PrefetchExecutor]] = None,
                backing=None,
                clock: Optional[Callable[[], float]] = None,
@@ -1215,13 +1071,7 @@ def open_cache(store, capacity: Optional[int] = None, *,
                retry=None,
                queue_depth: int = 4096,
                max_fetch_bytes: int = 4096,
-               degraded: bool = True,
-               breaker=None,
-               supervise: bool = True,
-               restart_budget: int = 3,
-               restart_window_s: float = 60.0,
-               heartbeat_s: Optional[float] = None,
-               rpc_timeout_s: float = 30.0) -> CacheClient:
+               breaker=None) -> CacheClient:
     """The one constructor path: store (instance or URI) + capacity →
     CacheClient.
 
@@ -1237,47 +1087,28 @@ def open_cache(store, capacity: Optional[int] = None, *,
     running :class:`~repro.daemon.CacheDaemon`, so ``open_cache``
     returns a connected ``RemoteCacheClient`` session instead of
     building an engine — ``capacity`` must be omitted (the daemon owns
-    engine configuration) and only ``fetch_bytes`` plus the URI's query
-    params apply.
+    engine configuration) and only ``fetch_bytes``, ``backing`` and the
+    URI's query params apply.
 
-    ``driver`` selects where the shard kernels run:
+    The kernel runs in this process (``make_engine``): the plain
+    ``IGTCache`` at ``n_shards=1``, the ``ShardedIGTCache`` facade
+    otherwise.
 
-    * ``"thread"`` (default) — in this process (``make_engine``:
-      the plain ``IGTCache`` at ``n_shards=1``, the ``ShardedIGTCache``
-      facade otherwise);
-    * ``"process"`` — one worker process per shard
-      (``core.procdriver.ProcessShardedCache``), ``n_procs`` of them
-      (defaults to ``n_shards`` when that is > 1, else 2), with fetched
-      bytes crossing through a shared-memory arena of ``arena_bytes``.
-
-    ``executor`` picks the prefetch transport: ``"sim"`` (deterministic
-    inline, virtual-clock callers), ``"threaded"`` (per-shard background
-    workers, wall-clock callers), ``"none"`` (read-only: candidates
-    cancelled), ``"process"`` (worker-resident fetch+complete — requires
-    ``driver="process"``), or a pre-built :class:`PrefetchExecutor`
-    instance.  When omitted it follows the driver: ``"sim"`` in-process,
-    ``"process"`` for the process driver.  ``retry`` is the
-    ``storage.api.RetryPolicy`` guarding every byte fetch.
-
-    Fault tolerance (see docs/RELIABILITY.md): ``degraded`` keeps reads
-    flowing around a dead shard (direct store fetches, counted in
-    ``ClientStats``); ``breaker`` is an optional
-    ``storage.api.CircuitBreaker`` guarding client-side fetches.  The
-    remaining knobs configure the process driver's supervisor and are
-    ignored by ``driver="thread"`` (in-process shards share this
-    process's fate — there is nothing to supervise): ``supervise``
-    (respawn dead shard workers), ``restart_budget`` restarts per
-    ``restart_window_s`` seconds before a shard goes permanently down,
-    ``heartbeat_s`` (liveness deadline for hung-worker detection, off by
-    default), and ``rpc_timeout_s`` (per-RPC reply deadline; a breach
-    kills and respawns the worker instead of hanging the caller).
+    ``executor`` picks the prefetch transport: ``"sim"`` (the default:
+    deterministic inline, virtual-clock callers), ``"threaded"``
+    (per-shard background workers, wall-clock callers), ``"none"``
+    (read-only: candidates cancelled), or a pre-built
+    :class:`PrefetchExecutor` instance.  ``retry`` is the
+    ``storage.api.RetryPolicy`` guarding every byte fetch; ``breaker``
+    is an optional ``storage.api.CircuitBreaker`` the default policy
+    adopts (see docs/RELIABILITY.md).
     """
     if isinstance(store, str):
         from ..storage.api import open_store
         store = open_store(store)
     if getattr(store, "is_cache_address", False):
         # cache://<sock-or-host:port> — a running CacheDaemon endpoint:
-        # the daemon already owns the engine (capacity, shards, driver,
+        # the daemon already owns the engine (capacity, shards,
         # executor), so the answer is a thin connected session, not a
         # locally constructed stack.  URI query params (?fetch_bytes=
         # true&label=trainer0) merge under explicit kwargs.
@@ -1303,58 +1134,25 @@ def open_cache(store, capacity: Optional[int] = None, *,
     if capacity is None:
         raise TypeError("open_cache() missing required argument: "
                         "'capacity' (only cache:// stores omit it)")
-    if driver not in ("thread", "process"):
-        raise ValueError(f"unknown driver {driver!r}; expected 'thread' "
-                         f"or 'process'")
     if executor is None:
-        executor = "process" if driver == "process" else "sim"
+        executor = "sim"
     if isinstance(executor, str) and executor not in _EXECUTORS:
-        # validate BEFORE constructing the engine: a process-backed
-        # engine spawns workers that must not leak over a typo
+        # validated before the engine is built: a typo costs nothing
         raise ValueError(
             f"unknown executor {executor!r}; expected one of "
             f"{sorted(_EXECUTORS)} or a PrefetchExecutor instance")
-    if driver == "process":
-        from .procdriver import DEFAULT_ARENA_BYTES, ProcessShardedCache
-        if n_procs is None:
-            n_procs = n_shards if n_shards > 1 else 2
-        engine: Engine = ProcessShardedCache(
-            store, capacity, cfg=cfg, options=options, n_procs=n_procs,
-            arena_bytes=(DEFAULT_ARENA_BYTES if arena_bytes is None
-                         else arena_bytes),
-            backing=backing,     # workers serve demand misses from it
-            retry=retry,
-            supervise=supervise, restart_budget=restart_budget,
-            restart_window_s=restart_window_s, heartbeat_s=heartbeat_s,
-            rpc_timeout_s=rpc_timeout_s)
-    else:
-        if n_procs is not None:
-            raise ValueError("n_procs only applies to driver='process'")
-        engine = make_engine(store, capacity, cfg=cfg, options=options,
-                             n_shards=n_shards)
+    engine = make_engine(store, capacity, cfg=cfg, options=options,
+                         n_shards=n_shards)
     if backing is None:
         backing = store          # normalized (or rejected) by CacheClient
     if isinstance(executor, str):
         if executor == "threaded":
             executor = ThreadedExecutor(queue_depth=queue_depth,
                                         max_fetch_bytes=max_fetch_bytes)
-        elif executor == "process":
-            from .procdriver import ProcessExecutor
-            executor = ProcessExecutor(queue_depth=queue_depth,
-                                       max_fetch_bytes=max_fetch_bytes)
         elif executor == "sim":
             executor = SimExecutor()
         else:
             executor = NullExecutor()
-    try:
-        client = CacheClient(engine, backing=backing, executor=executor,
-                             clock=clock, fetch_bytes=fetch_bytes,
-                             retry=retry, degraded=degraded,
-                             breaker=breaker)
-    except BaseException:
-        engine_close = getattr(engine, "close", None)
-        if engine_close is not None:     # never leak worker processes
-            engine_close()
-        raise
-    client._own_engine = True
-    return client
+    return CacheClient(engine, backing=backing, executor=executor,
+                       clock=clock, fetch_bytes=fetch_bytes, retry=retry,
+                       breaker=breaker)

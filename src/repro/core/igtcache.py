@@ -87,13 +87,6 @@ class BlockResult:
                 and self.hit == other.hit
                 and self.prefetched_hit == other.prefetched_hit)
 
-    def __reduce__(self):
-        # positional-args reduce: ~3× cheaper than the generic slotted
-        # __reduce_ex__ state dance — BlockResults cross the process
-        # boundary in every multi-process-driver read_batch reply
-        return (BlockResult, (self.key, self.size, self.hit,
-                              self.prefetched_hit))
-
     def __repr__(self) -> str:  # pragma: no cover
         return (f"BlockResult({self.key!r}, {self.size}, hit={self.hit}, "
                 f"pf={self.prefetched_hit})")

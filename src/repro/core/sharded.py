@@ -54,15 +54,14 @@ def shard_index(path: PathT, n_shards: int) -> int:
 
 
 class ShardRouting:
-    """Memoized path → shard routing, shared by every shard driver.
+    """Memoized path → shard routing of the sharded facade.
 
     The CRC-32 of the top-level component is computed **once per
     dataset**: routing for every subsequent access of that dataset is a
     single dict lookup (datasets are few; the memo is unbounded by
-    design).  Both the in-process ``ShardedIGTCache`` facade and the
-    multi-process ``core.procdriver.ProcessShardedCache`` inherit this,
-    so the two drivers cannot drift on placement — a path routes to the
-    same shard index under either."""
+    design).  ``ShardedIGTCache`` inherits this; the client's
+    ``KernelGuard`` and ``ThreadedExecutor`` route through the same
+    ``shard_id``, so a path's lock, worker and kernel shard agree."""
 
     def _init_routing(self, n_shards: int) -> None:
         if n_shards < 1:
@@ -85,9 +84,7 @@ class ShardRouting:
                         path_of=None) -> Dict[int, List[tuple]]:
         """Group indexed items by owning shard:
         ``{sid: [(original_index, item), ...]}`` — the one split-and-
-        reassemble-in-order primitive every batched fan-out uses (both
-        drivers' ``read_batch``, both executors' ``fetch_demand``), so
-        ordering/empty-bucket edge cases cannot drift between copies.
+        reassemble-in-order primitive of ``ShardedIGTCache.read_batch``.
         ``path_of`` extracts the routing path (default: ``item[0]``,
         the shape of read requests and range requests)."""
         buckets: Dict[int, List[tuple]] = {}
@@ -104,14 +101,11 @@ class ShardRouting:
 
 @dataclass
 class DemandSummary:
-    """One CMU's demand estimate, serialized for the cross-shard
-    allocation round.
+    """One CMU's demand estimate for the cross-shard allocation round.
 
-    This is the wire format of the rebalance-summary protocol: worker
-    processes ship these rows to the driver instead of live
-    ``CacheManageUnit`` objects, and the in-process facade builds the
-    same rows from its shards, so both drivers run the identical greedy
-    rule (``GlobalRebalancer.plan_moves``).  ``demand_limit`` carries
+    The planner (``GlobalRebalancer.plan_moves``) works on these rows,
+    not on live ``CacheManageUnit`` objects, so it is pure planning
+    that tests can drive with hand-made rows.  ``demand_limit`` carries
     enough state to re-evaluate ``wants_more`` after a mid-round quota
     move (RANDOM streams stop wanting at ``dataset_bytes``); patterns
     whose demand does not depend on quota leave it ``None``.
@@ -216,12 +210,11 @@ class GlobalRebalancer(Rebalancer):
                    max_moves: Optional[int] = None
                    ) -> List[Tuple[DemandSummary, DemandSummary, int]]:
         """Plan one cross-shard round over serialized demand rows — pure
-        planning, no engine access.  Both drivers run this: the
-        in-process facade applies the returned moves to live CMUs, the
-        process driver ships them to workers as quota/capacity deltas.
-        Rows are mutated in place (quota, headroom, ``want``,
-        ``wants_more`` via ``demand_limit``) so successive moves see
-        the post-move state, exactly like a live-object round would.
+        planning, no engine access: the facade applies the returned
+        moves to live CMUs.  Rows are mutated in place (quota, headroom,
+        ``want``, ``wants_more`` via ``demand_limit``) so successive
+        moves see the post-move state, exactly like a live-object round
+        would.
 
         ``cfg.quantum_policy`` selects the planner: ``"adaptive"``
         (default) sizes each move by the taker's measured unmet demand
@@ -388,8 +381,8 @@ class GlobalRebalancer(Rebalancer):
     def note_round(self, now: float, summaries: Sequence[ShardSummary],
                    moves: Sequence[tuple]) -> dict:
         """Record per-round stats and merge the shards' shipped sketches
-        into the cluster-wide heat view.  Both drivers call this once
-        per round; ``sim.cluster`` surfaces ``round_log`` as the
+        into the cluster-wide heat view, once per round;
+        ``sim.cluster`` surfaces ``round_log`` as the
         ``rebalance_trace``."""
         heat: Optional[CountMinSketch] = None
         hot: Optional[SpaceSaving] = None
@@ -439,17 +432,14 @@ class GlobalRebalancer(Rebalancer):
 
     def rebalance_shards(self, shards: Sequence[IGTCache], now: float,
                          max_moves: Optional[int] = None) -> List[tuple]:
-        """In-process round: summarize each shard (the same rows a worker
-        would ship), plan with the shared greedy rule, apply to the live
-        engines.  A cross-shard move shifts CMU quota and backing pool
-        capacity together, so total capacity is conserved and every
-        shard keeps ``sum(quota) == capacity``.
+        """One round: summarize each shard, plan with the greedy rule,
+        apply to the live engines.  A cross-shard move shifts CMU quota
+        and backing pool capacity together, so total capacity is
+        conserved and every shard keeps ``sum(quota) == capacity``.
 
         The facade plans over the full row set (it holds the live
-        objects anyway); the process driver plans over the wire
-        summaries' capped rows.  The cap only binds past ``cfg.topk``
-        workload CMUs per shard, where the tail carries negligible
-        weight by construction."""
+        objects anyway); the bounded ``ShardSummary`` payloads feed the
+        round's sketch merge and ``round_log``."""
         self.last_round = now
         rows: List[DemandSummary] = []
         live: List[CacheManageUnit] = []     # rows[i] describes live[i]
@@ -485,12 +475,10 @@ class GlobalRebalancer(Rebalancer):
 class ShardDemandTracker:
     """Per-shard demand summarization for the cross-shard round.
 
-    Lives next to the engine it measures: in-process the facade's
-    ``GlobalRebalancer`` holds one for all shards; under the process
-    driver each worker holds its own and ships the rows over the pipe
-    (the ``rebalance_summary`` command).  SKEWED demand is measured from
-    the BufferWindows' *cumulative* counters as deltas over this
-    tracker's own round interval — shard-local rounds reset the
+    Lives next to the engine it measures: the facade's
+    ``GlobalRebalancer`` holds one for all shards.  SKEWED demand is
+    measured from the BufferWindows' *cumulative* counters as deltas
+    over this tracker's own round interval — shard-local rounds reset the
     per-round counters on their own read-triggered phase, so the
     cumulative delta is the only phase-independent signal (see
     ``allocation.BufferWindow``)."""
@@ -596,7 +584,7 @@ class ShardDemandTracker:
         sketch is folded, read for the rows, serialized into the wire
         summary, then reset.
 
-        ``mark=True`` (the single-shard / worker-resident case) advances
+        ``mark=True`` (a tracker measuring one shard) advances
         the ghost marks to now; a tracker measuring several shards must
         pass ``mark=False`` per shard and call :meth:`mark_all` once
         with every shard's CMUs — marking per shard would reset the
@@ -656,7 +644,7 @@ class ShardDemandTracker:
 
 
 def split_capacity(capacity: int, n_shards: int) -> List[int]:
-    """Initial per-shard capacity partition (both drivers use this)."""
+    """Initial per-shard capacity partition."""
     base, rem = divmod(capacity, n_shards)
     return [base + (1 if i < rem else 0) for i in range(n_shards)]
 

@@ -19,8 +19,8 @@ module provides the two classic bounded-error summaries:
 
 Both serialize to bounded O(KB) payloads (zlib over the mostly-zero CMS
 table; length-prefixed entries for the top-k) so a shard's whole demand
-summary fits in a few wire KB regardless of block population —
-``ShardDemandTracker`` ships them over the rebalance RPC and
+summary fits in a few KB regardless of block population —
+``ShardDemandTracker`` builds them each rebalance round and
 ``GlobalRebalancer`` merges them into a cluster heat view.
 
 :class:`DemandSketch` is the per-shard composite the cache feeds on ghost

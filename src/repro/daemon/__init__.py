@@ -1,7 +1,7 @@
 """Cache-as-a-service: the network cache daemon and its thin clients.
 
-``CacheDaemon`` wraps one ``CacheClient`` (in-process sharded engine or
-the supervised multi-process driver) behind a framed socket protocol —
+``CacheDaemon`` wraps one ``CacheClient`` (the in-process engine, one
+shard or several) behind a framed socket protocol —
 Unix-domain socket by default, TCP optionally — so many independent
 processes share one unified cache (the Hoard deployment shape,
 arXiv:1812.00669).  ``RemoteCacheClient`` is the thin client;

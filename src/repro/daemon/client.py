@@ -1,9 +1,9 @@
 """``RemoteCacheClient``: the thin client for a ``CacheDaemon``.
 
 Satisfies the ``CacheClient`` read surface — ``read`` / ``read_batch``
-returning ``ReadResult`` objects (outcomes are ``core.wire.WireOutcome``
-views decoded lazily from the shared compact codec), plus the stats
-family (``stats`` / ``snapshot`` / ``hit_ratio`` / ``fault_stats``) and
+returning ``ReadResult`` objects (outcomes are ``daemon.wire.WireOutcome``
+views decoded lazily from the compact codec), plus the stats
+family (``stats`` / ``snapshot`` / ``hit_ratio``) and
 the kernel passthroughs (``tick`` / ``pin`` / ``never_cache`` /
 ``flush``) — but holds no kernel, no store, and no executor: every call
 is one framed request to the daemon.  ``open_cache("cache://...")``
@@ -33,8 +33,7 @@ without one.
 While ``down``, ``degraded=True`` (the default, requires a ``backing=``
 store for byte reads) serves reads straight from the backing store —
 all-miss outcomes from store geometry, bytes via ``fetch_many``,
-counted in ``client_stats`` exactly like the PR 6 shard-level degraded
-path.  ``degraded=False`` raises the typed
+counted in ``client_stats``.  ``degraded=False`` raises the typed
 :class:`~repro.core.faults.DaemonUnavailableError` instead.  Operations
 that *need* the daemon (stats, snapshots) always raise it while down;
 ``flush`` short-circuits to ``False``; ``tick`` becomes a no-op (the
@@ -64,10 +63,9 @@ from ..core.client import ClientStats, ReadResult
 from ..core.faults import DaemonUnavailableError
 from ..core.igtcache import BlockResult, ReadOutcome
 from ..core.types import PathT, block_key
-from ..core.wire import WireOutcome
 from ..storage.api import as_backing_store
 from .uri import DaemonAddress, parse_cache_uri
-from .wire import PROTO_VERSION, recv_msg, send_msg
+from .wire import PROTO_VERSION, WireOutcome, recv_msg, send_msg
 
 __all__ = ["RemoteCacheClient"]
 
@@ -396,8 +394,8 @@ class RemoteCacheClient:
     def _degraded_read(self, file_path: PathT, offset: int, size: int,
                        want: bool) -> ReadResult:
         """Serve one request without the daemon: all-miss outcome from
-        store geometry (mirroring ``CacheClient._degraded_outcome``),
-        bytes straight from the ``backing=`` store.  No cache
+        store geometry (the block decomposition the kernel would have
+        produced), bytes straight from the ``backing=`` store.  No cache
         observation happens — the restarted daemon's kernel re-learns
         this stream from its journal, not from reads it never saw."""
         bs = self.block_size
@@ -461,12 +459,6 @@ class RemoteCacheClient:
 
     def snapshot(self) -> dict:
         return self._request("snapshot")
-
-    def fault_stats(self) -> dict:
-        return self._request("fault_stats")
-
-    def shard_states(self):
-        return self._request("shard_states")
 
     def daemon_stats(self) -> dict:
         return self._request("daemon_stats")

@@ -1,21 +1,19 @@
 """``CacheDaemon``: the cache runtime as a network service.
 
 One daemon process owns the whole caching stack — a ``CacheClient``
-over either the in-process sharded engine or the supervised
-multi-process driver (``open_cache`` builds it; every knob passes
+over the in-process engine (``open_cache`` builds it; every knob passes
 through) — and serves any number of independent client processes over
 a Unix-domain socket (default) or TCP.  This is the Hoard deployment
 shape (arXiv:1812.00669): a per-node cache daemon with thin clients,
 so many trainer/serving processes share one unified cache and one
 store-metadata view instead of each re-materializing its own.
 
-Protocol: framed pickles (``daemon.wire``), request shapes lifted from
-the PR 5 worker pipes, read replies in the shared compact codec
-(``core.wire``).  Payload bytes for same-node clients cross a
-daemon-owned ``ShmArena`` (descriptors on the wire, bytes in shared
-memory, slots recycled via piggybacked frees); remote/TCP clients get
-the bytes streamed inline, and arena exhaustion spills to inline too
-(counted, like the process driver's spill path).
+Protocol: framed pickles (``daemon.wire``), read replies in its compact
+key-free outcome codec.  Payload bytes for same-node clients cross a
+daemon-owned ``ShmArena`` (``daemon.shm``: descriptors on the wire,
+bytes in shared memory, slots recycled via piggybacked frees);
+remote/TCP clients get the bytes streamed inline, and arena exhaustion
+spills to inline too (counted in ``daemon_stats()["spills"]``).
 
 Sessions and leases: every connection is a session with an id and a
 heartbeat lease.  *Any* frame renews the lease; a silent client (died
@@ -43,13 +41,12 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..core.client import CacheClient, open_cache
-from ..core.procdriver import ShmArena, _RegionAllocator
 from ..core.types import MB
-from ..core.wire import encode_outcome
 from .journal import CacheJournal
+from .shm import RegionAllocator, ShmArena
 from .uri import DaemonAddress, format_cache_uri
-from .wire import (ConnectionClosed, PROTO_VERSION, ProtocolError, recv_msg,
-                   send_msg)
+from .wire import (ConnectionClosed, PROTO_VERSION, ProtocolError,
+                   encode_outcome, recv_msg, send_msg)
 
 __all__ = ["CacheDaemon", "DEFAULT_LEASE_S", "DEFAULT_SNAPSHOT_EVERY_S"]
 
@@ -62,10 +59,7 @@ DEFAULT_SNAPSHOT_EVERY_S = 2.0
 
 
 def _pending_count(engine) -> int:
-    """Kernel pending-prefetch table size across any engine flavor."""
-    fn = getattr(engine, "pending_prefetch_count", None)
-    if callable(fn):
-        return fn()
+    """Kernel pending-prefetch table size, sharded or not."""
     shards = getattr(engine, "shards", None)
     if shards is not None:
         return sum(len(s._pending_prefetch) for s in shards)
@@ -100,9 +94,8 @@ class CacheDaemon:
     """Network front end over one ``CacheClient``.
 
     ``store``/``capacity`` plus ``**open_cache_kw`` build the inner
-    client exactly like :func:`~repro.core.client.open_cache` would
-    (``driver="process"`` puts the supervised shard workers behind the
-    daemon); alternatively pass a pre-built client as ``store``.
+    client exactly like :func:`~repro.core.client.open_cache` would;
+    alternatively pass a pre-built client as ``store``.
     ``uds`` names the listening socket path (a private temp path is
     created when neither ``uds`` nor ``host`` is given); ``host``/
     ``port`` select TCP instead.
@@ -160,13 +153,13 @@ class CacheDaemon:
             self.restore_stats = self._restore(self.journal)
         self._candidate_window = candidate_window
         self._block_size = self.client.cfg.block_size
-        self._arena = ShmArena(arena_bytes, 1) if arena_bytes > 0 else None
-        if self._arena is not None and self._arena.shm is not None:
-            self._alloc: Optional[_RegionAllocator] = \
-                _RegionAllocator(*self._arena.region(0))
-            self._arena_total = self._arena.region(0)[1]
-        else:
-            self._arena, self._alloc, self._arena_total = None, None, 0
+        self._arena: Optional[ShmArena] = None
+        self._alloc: Optional[RegionAllocator] = None
+        self._arena_total = 0
+        if arena_bytes > 0:
+            self._arena = ShmArena(arena_bytes)
+            self._alloc = RegionAllocator(0, self._arena.size)
+            self._arena_total = self._arena.size
         self._alloc_lock = threading.Lock()
         self._lock = threading.Lock()
         self._sessions: Dict[int, _Session] = {}
@@ -223,9 +216,7 @@ class CacheDaemon:
     def _restore(self, journal: CacheJournal) -> dict:
         """Warm-start from the journal directory: fold the snapshot and
         the replayed records into one manifest, then re-admit it into
-        the (fresh) engine.  Engines without ``warm_admit`` (the
-        process driver keeps kernel state worker-side) still get the
-        sticky pins/bans replayed — the documented degradation."""
+        the (fresh) engine."""
         t0 = time.monotonic()
         snap, records = journal.load()
         state = dict(snap or {})
@@ -250,18 +241,10 @@ class CacheDaemon:
             self._sticky_bans[p] = None
         out = {"snapshot": snap is not None, "records": len(records),
                "mode": "cold"}
-        warm = getattr(self.client.engine, "warm_admit", None)
-        if snap is None and not records:
-            pass                            # nothing durable yet: cold
-        elif callable(warm):
-            out.update(warm(state, time.monotonic()))
+        if snap is not None or records:     # else nothing durable yet
+            out.update(self.client.engine.warm_admit(state,
+                                                     time.monotonic()))
             out["mode"] = "warm"
-        else:
-            for p in state["pins"]:
-                self.client.pin(p)
-            for p in state["never_cache"]:
-                self.client.never_cache(p)
-            out["mode"] = "sticky-only"
         out["restore_s"] = time.monotonic() - t0
         return out
 
@@ -279,8 +262,7 @@ class CacheDaemon:
         log.  Returns False when no journal is configured."""
         if self.journal is None:
             return False
-        ws = getattr(self.client.engine, "warm_state", None)
-        state = ws() if callable(ws) else {}
+        state = self.client.engine.warm_state()
         with self._lock:
             pins = {tuple(p) for p in state.get("pins", ())}
             pins.update(self._sticky_pins)
@@ -524,10 +506,6 @@ class CacheDaemon:
             return c.snapshot()
         if op == "hit_ratio":
             return c.hit_ratio()
-        if op == "fault_stats":
-            return c.fault_stats()
-        if op == "shard_states":
-            return c.shard_states()
         if op == "tick":
             c.tick(payload)
             return None
@@ -579,7 +557,7 @@ class CacheDaemon:
     def _stage(self, sess: _Session, data):
         """Payload placement: arena slot descriptor for same-node
         sessions, inline bytes otherwise (and on arena exhaustion —
-        counted as a spill, like the process driver)."""
+        counted as a spill)."""
         if data is None:
             return None
         arr = np.asarray(data, dtype=np.uint8)

@@ -1,21 +1,19 @@
 """``DaemonSupervisor``: keep one cache daemon alive on a fixed socket.
 
-The PR 6 shard supervisor answered *fault of the worker* — a shard
-process dies, the driver respawns it inside a :class:`RestartBudget`.
-This module lifts the same shape one level: the unit of failure is the
-whole daemon.  A supervisor owns a ``factory()`` that builds-and-starts
-a :class:`~repro.daemon.server.CacheDaemon` **on the same socket path**
+The unit of failure is the whole daemon.  A supervisor owns a
+``factory()`` that builds-and-starts a
+:class:`~repro.daemon.server.CacheDaemon` **on the same socket path**
 every time (clients reconnect to the address they already know — no
 re-discovery protocol), a monitor thread that notices a crashed daemon,
-and the same sliding-window budget semantics: a daemon that keeps
-dying (poisoned journal, bad disk) stops being respawned and the
-supervisor converges to a stable ``down`` state — clients with
-``degraded=True`` keep serving reads from the backing store.
+and a sliding-window :class:`RestartBudget`: a daemon that keeps dying
+(poisoned journal, bad disk) stops being respawned and the supervisor
+converges to a stable ``down`` state — clients with ``degraded=True``
+keep serving reads from the backing store.
 
-State machine mirrors the shard vocabulary (``up`` / ``restarting`` /
-``down``); transitions land in ``events`` with wall-clock timestamps
-and, for each respawn, the measured ``recovery_s`` (factory return to
-listening socket — the number the recovery benchmark reports).
+States are ``up`` / ``restarting`` / ``down`` (``core.faults``);
+transitions land in ``events`` with wall-clock timestamps and, for each
+respawn, the measured ``recovery_s`` (factory return to listening
+socket — the number the recovery benchmark reports).
 
 In-process by design: the daemon here is an object, not a child
 process, so "crash" means :meth:`CacheDaemon.crash` (sockets die

@@ -163,14 +163,11 @@ class ClusterSim:
             self.client.executor.tier = self._tier
         self.trace_alloc = trace_alloc
         self.stop_job_at = stop_job_at       # (job_id, time): forced stop (Fig 11)
-        # (virtual time, kind, sid) strikes against a process-backed
-        # engine: the chaos arc (kill → degraded reads → respawn →
-        # re-warm) plays out inside the simulated trace.  Worker strikes
-        # (kill/suspend/resume) need a multi-process driver (sim.chaos);
-        # "client_kill" strikes target ``chaos_clients[sid]`` instead —
-        # daemon clients registered as victims, so a trace can lose a
-        # remote cache client mid-run and the daemon's lease reclaim
-        # plays out alongside the simulated workload.
+        # (virtual time, kind, sid) strikes (sim.chaos): "client_kill"
+        # targets ``chaos_clients[sid]`` — daemon clients registered as
+        # victims, so a trace can lose a remote cache client mid-run and
+        # the daemon's lease reclaim plays out alongside the simulated
+        # workload.
         self.chaos_events = list(chaos_events or [])
         self.chaos_clients = list(chaos_clients or [])
         # a DaemonSupervisor: the victim of daemon_kill/daemon_restart
@@ -242,8 +239,6 @@ class ClusterSim:
                 payload(self)
         jct = {jid: t - self._jobs[jid].submit_time
                for jid, t in self._done.items()}
-        if self._chaos is not None:       # never leave a worker wedged
-            self._chaos.resume_all()
         util = self.link.busy_time / max(1e-9, self.now)
         reb = getattr(self.engine, "global_rebalancer", None)
         # remote mode: the daemon owns the kernel — ask over the wire
@@ -267,19 +262,8 @@ class ClusterSim:
     def _strike(self, kind: str, sid: int) -> None:
         if self._chaos is None:
             from .chaos import ChaosMonkey
-            driver_like = (hasattr(self.engine, "_channels")
-                           and hasattr(self.engine, "_kill_worker"))
-            if driver_like or not (self.chaos_clients
-                                   or self.chaos_daemon is not None):
-                # preserves the TypeError for worker strikes against an
-                # in-process engine with no other victims either
-                self._chaos = ChaosMonkey(self.engine,
-                                          clients=self.chaos_clients,
-                                          daemon=self.chaos_daemon)
-            else:
-                self._chaos = ChaosMonkey(None,
-                                          clients=self.chaos_clients,
-                                          daemon=self.chaos_daemon)
+            self._chaos = ChaosMonkey(clients=self.chaos_clients,
+                                      daemon=self.chaos_daemon)
         self._chaos.strike(kind, sid)
         self._chaos_log.append({"t": self.now, "kind": kind, "sid": sid})
 

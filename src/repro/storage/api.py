@@ -557,9 +557,8 @@ class FaultyStore(BackingStore):
         return self._backing.capabilities()
 
     def __getstate__(self):
-        # picklable for spawn/forkserver shard workers (the lock is
-        # process-local state; each process draws from its own copy of
-        # the seeded RNG stream)
+        # picklable (the lock is process-local state; each process
+        # draws from its own copy of the seeded RNG stream)
         state = self.__dict__.copy()
         del state["_lock"]
         return state
@@ -569,7 +568,7 @@ class FaultyStore(BackingStore):
         self._lock = threading.Lock()
 
     def _roll(self, what: str) -> bool:
-        # concurrent shard workers + readers all fetch through here —
+        # concurrent executor workers + readers all fetch through here —
         # draw and count under one lock so the injection counters stay
         # exact (the retry-accounting tests equate them to stats.retries).
         # Returns whether this fetch's payload should come back corrupt.
@@ -727,8 +726,8 @@ def open_store(uri: str, **overrides):
         wrapper = FaultyStore(inner, **fault_kw)
         # stamp the *composed* URI on the wrapper: without it,
         # ``store_spec`` would read ``uri``/``reopen_by_uri`` through
-        # ``__getattr__`` delegation from the inner store and a respawned
-        # worker would silently reconstruct the stack *without* fault
+        # ``__getattr__`` delegation from the inner store and another
+        # process would silently reconstruct the stack *without* fault
         # injection (the registry double-wrap bug)
         _record_uri(wrapper, uri)
         return wrapper
@@ -761,13 +760,14 @@ def _record_uri(store, uri: str) -> None:
 
 
 def store_spec(store):
-    """Picklable recipe to reconstruct ``store`` in a worker process.
+    """Picklable recipe to reconstruct ``store`` in another process.
 
-    The multi-process shard driver gives every worker its own store
-    instance (per-process file handles / connections, per-process
-    capability negotiation) instead of sharing one across the fork:
+    A process that serves the cache apart from this one (an
+    out-of-process daemon factory) should get its own store instance
+    (per-process file handles / connections, per-process capability
+    negotiation) instead of sharing one across the fork:
 
-    * a URI string travels as ``("uri", uri)`` — the worker calls
+    * a URI string travels as ``("uri", uri)`` — the other process calls
       ``open_store`` afresh, re-negotiating capabilities against its own
       instance; a store *object* does so only when its class opts in
       with ``reopen_by_uri = True`` (``LocalFSStore``: the whole state
@@ -787,7 +787,7 @@ def store_spec(store):
 
 
 def resolve_store_spec(spec, **overrides):
-    """Worker-side inverse of :func:`store_spec`."""
+    """The receiving process's inverse of :func:`store_spec`."""
     kind, payload = spec
     if kind == "uri":
         return open_store(payload, **overrides)

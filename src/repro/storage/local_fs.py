@@ -31,8 +31,8 @@ class LocalFSStore(StoreMetaIndex, BackingStore):
     """Directory-tree store: sorted-listing metadata snapshot + ranged
     reads of the underlying files."""
 
-    # the whole store state derives from the walked directory, so a
-    # worker process can faithfully reconstruct it from the URI alone
+    # the whole store state derives from the walked directory, so
+    # another process can faithfully reconstruct it from the URI alone
     # (storage.api.store_spec → per-process re-open + re-negotiation)
     reopen_by_uri = True
 

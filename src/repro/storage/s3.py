@@ -26,11 +26,11 @@ same protocol from objects registered via :meth:`MockS3Server.add_object`
 No test touches the network: the socket never leaves loopback.
 
 The ``mock-s3://<name>/<bucket>?dirs=D&files=N&file_kb=K&seed=S`` scheme
-goes one step further for the process driver: the URI *is* the bucket
-spec.  A per-process registry maps (name, bucket, spec) to a running
-mock server, so ``store_spec``/``resolve_store_spec`` round-trips — a
-respawned shard worker re-opens the URI and gets its own identical
-deterministic server (content is seeded by path, not by process).
+goes one step further: the URI *is* the bucket spec.  A per-process
+registry maps (name, bucket, spec) to a running mock server, so
+``store_spec``/``resolve_store_spec`` round-trips — another process
+re-opens the URI and gets its own identical deterministic server
+(content is seeded by path, not by process).
 """
 from __future__ import annotations
 

@@ -7,8 +7,9 @@ as a new layer between the client and any backing store: a **RAM tier**
 holding whole-block payloads, spilling its evictions to a **local-disk
 tier** (real checksummed files under a spill directory with their own
 capacity, LRU order and promote-on-hit), composed behind the ordinary
-``BackingStore`` v2 surface — ``open_cache`` stacks (thread driver,
-process driver, the PR 8 daemon) get tiering with zero API changes:
+``BackingStore`` v2 surface — ``open_cache`` stacks (one shard or
+several, behind the cache daemon or not) get tiering with zero API
+changes:
 
     store = open_store("tiered+file:///data?ram_mb=64&disk_mb=256")
     client = open_cache(store, capacity, fetch_bytes=True)

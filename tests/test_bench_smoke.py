@@ -26,16 +26,6 @@ def test_overhead_smoke_emits_json(tmp_path):
         point = payload["sharded"][n]
         assert point["us_per_access"] > 0
         assert point["nodes"] > 0
-    # multi-process driver axis (merged section, --procs 1,2,4): the
-    # kernel loop and the in-process facade ride along for the
-    # interleaved comparison.  Smoke asserts presence, not ordering —
-    # the down-scaled run is too short for a meaningful race.
-    axis = payload["proc_path"]
-    assert axis["smoke"] is True
-    for key in ("kernel_1", "facade_4", "proc_1", "proc_2", "proc_4"):
-        assert axis[key]["us_per_access"] > 0
-    assert "speedup_4p_vs_1p" in axis
-    assert "speedup_4p_vs_kernel" in axis
     # rebalance_path axis (merged section; smoke runs the first shard
     # count >1 only): sketch-based demand summaries drive the planner —
     # CHR gap vs unsharded recorded for both quantum policies, and the
@@ -105,34 +95,6 @@ def test_store_micro_smoke(tmp_path):
     assert axis["synthesis"]["synth_under_transfer"] is True
     assert axis["synthesis"]["synth_4mb_ms"] < \
         axis["synthesis"]["transfer_4mb_ms"]
-
-
-def test_fault_micro_smoke(tmp_path):
-    """--smoke availability axis: kill a shard worker mid-trace, record
-    recovery time, degraded-read cost and post-recovery CHR gap, merged
-    into the shared overhead JSON without clobbering other sections."""
-    from benchmarks import fault_micro
-
-    out = tmp_path / "BENCH_overhead.json"
-    out.write_text(json.dumps({"results": {"10000": {"us_per_access": 1}}}))
-    rows = fault_micro.main(smoke=True, json_path=out)
-    assert rows, "fault_path smoke produced no CSV rows"
-    payload = json.loads(out.read_text())
-    assert payload["results"]["10000"]["us_per_access"] == 1  # preserved
-    axis = payload["fault_path"]
-    assert axis["smoke"] is True
-    # the worker was killed, respawned within budget, and the client
-    # actually served reads around the dead shard
-    assert axis["recovery_s"] is not None and axis["recovery_s"] > 0
-    assert axis["chaos"]["restarts"] >= 1
-    assert axis["chaos"]["degraded_reads"] > 0
-    assert axis["chaos"]["degraded_bytes"] > 0
-    assert all(s == "up" for s in axis["chaos"]["shard_states"].values())
-    assert axis["baseline"]["us_per_batch"] > 0
-    assert axis["chaos"]["degraded_batch_us"] > 0
-    # gap is recorded (the 5 % bound is asserted by the chaos e2e test
-    # on a long-enough trace, not by the down-scaled smoke run)
-    assert "chr_gap_pct" in axis
 
 
 def test_daemon_micro_smoke(tmp_path):

@@ -153,6 +153,26 @@ def test_open_cache_rejects_unknown_executor():
         open_cache(store, 64 * MB, cfg=CFG, executor="warp-drive")
 
 
+def test_open_cache_takes_no_driver_knobs(monkeypatch):
+    """The executor strings are the three in-process ones, checked
+    before any engine is built, and driver knobs are no parameters."""
+    import repro.core.client as client_mod
+    built = []
+    real = client_mod.make_engine
+    monkeypatch.setattr(client_mod, "make_engine",
+                        lambda *a, **kw: built.append(1) or real(*a, **kw))
+    store = mk_store()
+    with pytest.raises(ValueError, match="unknown executor"):
+        open_cache(store, 64 * MB, cfg=CFG, executor="process")
+    assert built == []
+    with pytest.raises(TypeError):
+        open_cache(store, 64 * MB, cfg=CFG, driver="thread")
+    assert built == []
+    for name in ("sim", "threaded", "none"):
+        open_cache(store, 64 * MB, cfg=CFG, executor=name).close()
+    assert len(built) == 3
+
+
 def test_submit_after_close_raises_and_releases():
     """Close-vs-submit race (ISSUE 5 satellite): a submit that loses the
     race against close() must raise cleanly instead of enqueueing into a
@@ -186,6 +206,19 @@ class FailingStore:
         if self.fail:
             raise IOError("backend down")
         return self.store.fetch_block(path, size)
+
+
+def test_client_shard_queue_wait_idle_reports_closed_promptly():
+    """A closed per-shard queue with outstanding work can only drain
+    through close()'s cancellation sweep — wait_idle must report False
+    at once, not burn the caller's timeout."""
+    from repro.core.client import _ShardQueue
+    q = _ShardQueue(depth=8)
+    q.outstanding = 2
+    q.closed = True
+    t0 = time.monotonic()
+    assert q.wait_idle(30.0) is False
+    assert time.monotonic() - t0 < 1.0
 
 
 def test_demand_fetch_after_close_raises_instead_of_hanging():

@@ -8,10 +8,10 @@ identity-hit accounting exact, and the fault-of-the-client arc leaks
 nothing: a client that dies mid-read — silently (lease expiry) or with
 an EOF (disconnect mid-``read_batch``) — gets its arena slots freed,
 its prefetch-candidate window cancelled, and the executor conservation
-identity ``submitted == completed + cancelled + deduped`` holds,
-under both the in-process ThreadedExecutor engine and the supervised
-multi-process driver.  The chaos harness drives the same arc from a
-``ClusterSim`` trace via the new ``client_kill`` strike.
+identity ``submitted == completed + cancelled + deduped`` holds.  The
+same-node payload arena serves store-exact bytes and recycles its
+slots.  The chaos harness drives the same arc from a ``ClusterSim``
+trace via the ``client_kill`` strike.
 
 Every test runs under a hard SIGALRM guard: a deadlocked serve thread
 or a lost reply must fail the test, not hang tier-1.
@@ -28,10 +28,10 @@ import pytest
 
 from repro.core import CacheConfig, MB, block_key, open_cache, path_key
 from repro.core.igtcache import BlockResult, ReadOutcome
-from repro.core.wire import WireOutcome, encode_outcome
 from repro.daemon import CacheDaemon, RemoteCacheClient
-from repro.daemon.wire import PROTO_VERSION, recv_msg, send_msg
-from repro.sim.chaos import ChaosMonkey, plan_strikes
+from repro.daemon.wire import (PROTO_VERSION, WireOutcome, encode_outcome,
+                               recv_msg, send_msg)
+from repro.sim.chaos import ChaosMonkey, ChaosSchedule, plan_strikes
 from repro.sim.cluster import ClusterSim
 from repro.sim.workloads import make_paper_suite
 from repro.storage import RemoteStore, make_dataset
@@ -95,7 +95,7 @@ def test_wire_codec_roundtrip_seeded():
     """encode → pickle → decode must reproduce the ``ReadOutcome``
     bitwise: every block (key, size, hit, prefetched_hit), the byte
     tallies, and the candidate list.  Re-encoding a ``WireOutcome`` is
-    the identity (the daemon proxies driver outcomes for free)."""
+    the identity, not a copy."""
     rng = random.Random(1234)
     for case in range(200):
         fp = (f"ds{rng.randrange(3)}", f"dir{rng.randrange(4)}",
@@ -124,6 +124,14 @@ def test_wire_codec_roundtrip_seeded():
         assert wo.prefetches == out.prefetches
         # re-encode of an already-wire outcome: the identity, not a copy
         assert encode_outcome(wo, first) is enc
+
+
+def test_wire_outcome_reconstructs_keys():
+    enc = (3, [4, 5], 0b01, 0b00, [])
+    out = WireOutcome(enc, ("ds", "a", "f.bin"))
+    assert [b.key for b in out.blocks] == ["ds/a/f.bin/#3", "ds/a/f.bin/#4"]
+    assert out.blocks[0].hit and not out.blocks[1].hit
+    assert out.remote_bytes == 5 and out.cached_bytes == 4
 
 
 # ---------------------------------------------------------------------------
@@ -312,23 +320,6 @@ def test_client_kill_lease_reclaim_threaded():
             assert fresh.read(f.path, 0, f.size).data.size == f.size
 
 
-def test_client_kill_lease_reclaim_process_driver():
-    """Same arc with the supervised multi-process driver behind the
-    daemon: payload bytes cross worker arena → daemon arena → client,
-    and the ProcessExecutor's conservation identity must survive the
-    dead session."""
-    store = mk_store()
-    with CacheDaemon(store, 48 * MB, cfg=CFG, lease_s=0.3,
-                     driver="process", n_procs=2, arena_bytes=8 * MB,
-                     rpc_timeout_s=15.0) as d:
-        victim = RemoteCacheClient(d.uri, fetch_bytes=True, heartbeat=False)
-        outs = _read_some(victim, all_files(store)[:10])
-        assert all(r.data is not None for r in outs)
-        victim.kill()
-        _assert_reclaimed_to_baseline(d, reaped=1)
-        assert all(s == "up" for s in d.client.shard_states())
-
-
 def test_disconnect_mid_read_batch_leaks_nothing():
     """The EOF path: a raw client sends a fetching ``read_batch`` and
     closes the socket without ever reading the reply.  The daemon must
@@ -405,37 +396,100 @@ def test_lease_expiry_races_reconnect_no_double_free():
 
 
 # ---------------------------------------------------------------------------
+# same-node payload arena
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+def test_arena_serves_store_bytes_and_recycles_slots(n_shards):
+    """Same-node payloads cross the daemon's shared-memory arena: the
+    bytes match the store's, and reading far more than the arena holds
+    needs no inline spill — each slot's free rides the next request."""
+    store = mk_store(4)
+    files = all_files(store)
+    with CacheDaemon(store, 48 * MB, cfg=CFG, n_shards=n_shards,
+                     executor="threaded", arena_bytes=1 * MB) as d:
+        with open_cache(d.uri, fetch_bytes=True) as c:
+            assert c._shm is not None, "session was not granted the arena"
+            total = 0
+            for rep in range(2):                # miss pass, then hit pass
+                for i in range(0, len(files), 3):
+                    chunk = files[i:i + 3]
+                    outs = _read_some(c, chunk, now=rep * 100.0 + i)
+                    for f, res in zip(chunk, outs):
+                        want = np.asarray(
+                            store.fetch_range(f.path, 0, f.size),
+                            dtype=np.uint8)
+                        assert np.array_equal(res.data, want), f.path
+                        total += f.size
+            st = d.daemon_stats()
+            assert total > 8 * st["arena_total"]
+            assert st["spills"] == 0, "payload bytes fell back inline"
+            assert d.client.stats.hits > 0
+        _assert_reclaimed_to_baseline(d)
+
+
+# ---------------------------------------------------------------------------
 # chaos harness: the client_kill strike
 # ---------------------------------------------------------------------------
 
 def test_plan_strikes_client_kill_deterministic():
-    a = plan_strikes(60, n_shards=4, seed=3, n_strikes=6,
-                     kinds=("kill", "client_kill"), n_clients=3)
-    b = plan_strikes(60, n_shards=4, seed=3, n_strikes=6,
-                     kinds=("kill", "client_kill"), n_clients=3)
+    a = plan_strikes(60, seed=3, n_strikes=6,
+                     kinds=("daemon_kill", "client_kill"), n_clients=3)
+    b = plan_strikes(60, seed=3, n_strikes=6,
+                     kinds=("daemon_kill", "client_kill"), n_clients=3)
     assert a == b
     kinds = {s.kind for s in a}
-    assert kinds <= {"kill", "client_kill"}
+    assert kinds <= {"daemon_kill", "client_kill"}
     for s in a:
         if s.kind == "client_kill":
             assert 0 <= s.sid < 3
+        else:
+            assert s.sid == 0
     with pytest.raises(ValueError, match="n_clients"):
-        plan_strikes(60, n_shards=4, kinds=("client_kill",))
+        plan_strikes(60, kinds=("client_kill",))
+    with pytest.raises(ValueError, match="plannable"):
+        plan_strikes(60, kinds=("kill",), n_clients=1)
 
 
 def test_chaos_monkey_client_kill_needs_victims():
     with pytest.raises(TypeError):
-        ChaosMonkey(None)                      # nothing at all to strike
+        ChaosMonkey()                          # nothing at all to strike
     store = mk_store(1)
     with CacheDaemon(store, 16 * MB, cfg=CFG, lease_s=0.3) as d:
         victim = RemoteCacheClient(d.uri, heartbeat=False)
-        monkey = ChaosMonkey(None, clients=[victim])
-        with pytest.raises(RuntimeError, match="process driver"):
-            monkey.kill(0)                     # worker strikes untargeted
+        monkey = ChaosMonkey(clients=[victim])
+        with pytest.raises(RuntimeError, match="supervised daemon"):
+            monkey.strike("daemon_kill", 0)    # no daemon registered
+        with pytest.raises(ValueError, match="unknown strike kind"):
+            monkey.strike("kill", 0)
         monkey.strike("client_kill", 0)
         assert monkey.strikes[-1]["kind"] == "client_kill"
         wait_until(lambda: d.daemon_stats()["reaped"] == 1,
                    what="monkey-killed client reaped")
+
+
+def test_chaos_schedule_fires_each_planned_strike_once():
+    store = mk_store(1)
+    with CacheDaemon(store, 16 * MB, cfg=CFG, lease_s=0.3) as d:
+        # heartbeats on: only a struck victim goes silent and is reaped
+        victims = [RemoteCacheClient(d.uri) for _ in range(3)]
+        plan = plan_strikes(10, seed=7, n_strikes=2, n_clients=3)
+        sched = ChaosSchedule(ChaosMonkey(clients=victims), plan)
+        fired = []
+        for step in range(10):
+            fired += sched.on_step(step)
+        assert fired == plan == sched.fired
+        assert all(sched.on_step(s.step) == [] for s in plan)
+        killed = {s.sid for s in plan}
+        assert [k["sid"] for k in sched.monkey.strikes] == \
+            [s.sid for s in plan]
+        wait_until(lambda: d.daemon_stats()["reaped"] >= len(killed),
+                   what="scheduled victims reaped")
+        time.sleep(0.5)                        # past another lease
+        assert d.daemon_stats()["reaped"] == len(killed)
+        for i, v in enumerate(victims):
+            if i not in killed:
+                v.close()
 
 
 def test_cluster_sim_client_kill_strike_mid_trace():

@@ -55,7 +55,7 @@ def test_same_path_same_shard_always():
 def test_routing_hashes_once_per_dataset(monkeypatch):
     """Memoized routing (ISSUE 5 satellite): the CRC-32 runs once per
     top-level component, not once per access — every later access is a
-    dict lookup on both drivers (ShardRouting mixin)."""
+    dict lookup (ShardRouting mixin)."""
     import repro.core.sharded as sh
     calls = []
     real = sh.zlib.crc32
@@ -455,3 +455,54 @@ def test_ghost_mark_table_stays_bounded():
     tracker.summarize(eng, 0, 99.0)
     assert len(tracker._ghost_mark) == len(eng.cache.cmus)
     assert len(tracker._ema) == len(eng.cache.cmus)
+
+
+# ------------------------------------------- planner vs the live round
+
+
+def _skewed_pair(store, cfg):
+    s0 = IGTCache(store, 32 * MB, cfg=cfg)
+    s1 = IGTCache(store, 32 * MB, cfg=cfg)
+    cmu = s0.cache.create_cmu(("ds0",), 128 * MB, now=0.0)
+    cmu.flat_pattern = Pattern.SKEWED
+    for i in range(50):
+        cmu.note_access(i * 0.01)
+        cmu.buffer_window.on_evict(f"k{i}")
+        cmu.buffer_window.probe(f"k{i}")
+    return s0, s1
+
+
+def test_plan_moves_matches_live_rebalancer():
+    """The serialized planner is the same greedy rule the live
+    cross-shard round applies (one skewed taker, one idle donor) —
+    checked under both move-sizing policies: fixed ships exactly one
+    quantum, adaptive sizes the move by the measured want."""
+    import dataclasses
+    cfg = CacheConfig(min_share=4 * MB, rebalance_quantum=4 * MB,
+                      window=40, reanalyze_every=20, node_cap=500)
+    store = mk_store(4)
+    fixed_cfg = dataclasses.replace(cfg, quantum_policy="fixed")
+    s0, s1 = _skewed_pair(store, fixed_cfg)
+    reb = GlobalRebalancer(fixed_cfg)
+    rows = [r for r, _ in reb.tracker.summarize(s0, 0, 1.0, mark=False)]
+    rows += [r for r, _ in reb.tracker.summarize(s1, 1, 1.0, mark=False)]
+    moves = reb.plan_moves(rows)
+    assert moves, "skewed demand must pull capacity cross-shard"
+    donor, taker, amt = moves[0]
+    assert taker.key == ("ds0",) and taker.shard == 0
+    assert donor.shard == 1
+    assert amt == cfg.rebalance_quantum
+
+    s0, s1 = _skewed_pair(store, cfg)        # adaptive (default policy)
+    reb = GlobalRebalancer(cfg)
+    rows = [r for r, _ in reb.tracker.summarize(s0, 0, 1.0, mark=False)]
+    rows += [r for r, _ in reb.tracker.summarize(s1, 1, 1.0, mark=False)]
+    moves = reb.plan_moves(rows)
+    assert moves
+    donor, taker, amt = moves[0]
+    assert taker.key == ("ds0",) and taker.shard == 0
+    assert donor.shard == 1
+    # want-sized: 50 distinct ghost-hit blocks x block_size, capped by
+    # the donor's headroom — strictly more than one fixed quantum
+    assert amt > cfg.rebalance_quantum
+    assert amt <= 50 * cfg.block_size

@@ -10,6 +10,7 @@ cancellation (no kernel pending-table leak).
 """
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -22,6 +23,7 @@ from repro.storage import (FaultyStore, LegacyStoreAdapter, LocalFSStore,
                            StoreCapabilities, StoreError, TransientStoreError,
                            as_backing_store, make_dataset, open_store,
                            registered_schemes)
+from repro.storage.api import DeadlineError, store_spec
 
 CFG = CacheConfig(min_share=4 * MB, rebalance_quantum=4 * MB,
                   window=40, reanalyze_every=20)
@@ -365,6 +367,31 @@ def _sim_world():
                            small_file_size=256 * 1024))
     store.add(make_dataset("big", "big_files", n_files=4, file_size=16 * MB))
     return store
+
+
+def test_store_hang_hits_retry_deadline():
+    """An endlessly-flaky, hanging store costs a *bounded* wait: the
+    retry deadline converts the stall into DeadlineError instead of
+    sleeping through the full backoff ladder."""
+    inner = MemStore(block_size=1 * MB)
+    inner.add_file(("a", "f"), np.zeros(1 * MB, dtype=np.uint8))
+    flaky = FaultyStore(inner, fail_rate=1.0, hang_rate=1.0, hang_s=0.05,
+                        seed=3)
+    pol = RetryPolicy(max_attempts=100, backoff_s=0.01,
+                      deadline_s=0.25)
+    t0 = time.monotonic()
+    with pytest.raises(DeadlineError):
+        pol.call(flaky.fetch_range, ("a", "f#b0"), 0, 1024)
+    assert time.monotonic() - t0 < 5.0, "deadline did not bound the wait"
+
+
+def test_store_spec_roundtrip():
+    # object spec: a populated RemoteStore must travel as itself
+    store = _sim_world()
+    kind, payload = store_spec(store)
+    assert kind == "object" and payload is store
+    # URI spec: strings re-open in the receiving process
+    assert store_spec("sim://default") == ("uri", "sim://default")
 
 
 def test_transient_faults_absorbed_with_retry_accounting():
